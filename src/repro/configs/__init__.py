@@ -1,4 +1,5 @@
-"""Config registry: ``get_config(arch_id)`` / ``get_smoke_config(arch_id)``."""
+"""Config registry: ``get_config(arch_id)`` / ``get_smoke_config(arch_id)``,
+and ``cut_depth`` for a chip's share of a published config."""
 from __future__ import annotations
 
 import importlib
@@ -34,3 +35,26 @@ def get_config(arch_id: str) -> ModelConfig:
 
 def get_smoke_config(arch_id: str) -> ModelConfig:
     return _mod(arch_id).smoke_config()
+
+
+def cut_depth(cfg: ModelConfig, n_layers: int) -> ModelConfig:
+    """``cfg`` with only its first ``n_layers`` layers; every width stays.
+
+    Whole repeats of each segment's pattern are kept in order, and a
+    pattern cut midway keeps its leading layers as one extra segment.
+    """
+    if not 0 < n_layers <= cfg.n_layers:
+        raise ValueError(f"n_layers must be in [1, {cfg.n_layers}] for "
+                         f"{cfg.name}; got {n_layers}")
+    segments, left = [], n_layers
+    for pattern, repeats in cfg.segments:
+        whole = min(repeats, left // len(pattern))
+        if whole:
+            segments.append((pattern, whole))
+            left -= whole * len(pattern)
+        if left and whole < repeats:
+            segments.append((pattern[:left], 1))
+            left = 0
+        if not left:
+            break
+    return cfg.with_(n_layers=n_layers, segments=tuple(segments))

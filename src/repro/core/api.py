@@ -217,13 +217,14 @@ class LeafwiseIntN(WireCodec):
     """
 
     block: int = DEFAULT_BLOCK
-    impl: str = "ref"
+    impl: str | None = None          # None: the kernel on TPU, else ref
     bits: int = 8
     error_feedback: bool = False
 
     def __post_init__(self):
         from repro.kernels.quantize import check_bits
         check_bits(self.bits)
+        object.__setattr__(self, "impl", kops.resolve_impl(self.impl))
 
     @property
     def name(self):
@@ -307,13 +308,14 @@ class FlatFusedIntN(WireCodec):
     :class:`FlatFusedInt8`."""
 
     block: int = DEFAULT_BLOCK
-    impl: str = "ref"
+    impl: str | None = None          # None: the kernel on TPU, else ref
     bits: int = 8
     error_feedback: bool = False
 
     def __post_init__(self):
         from repro.kernels.quantize import check_bits
         check_bits(self.bits)
+        object.__setattr__(self, "impl", kops.resolve_impl(self.impl))
 
     @property
     def name(self):
@@ -442,7 +444,6 @@ def _make_weighted_psum_aggregate(aggregator, codec, mesh, param_specs,
     the mean: ``aggregate(stacked, weights, residual) -> (mixed, new_res)``
     with the residual sharded like the params (leafwise mirror tree)."""
     from jax.sharding import PartitionSpec as P
-    from repro.sharding import compat
 
     if getattr(codec, "stateful", False):
         def aggregate_ef(stacked, weights, residual):
@@ -457,7 +458,7 @@ def _make_weighted_psum_aggregate(aggregator, codec, mesh, param_specs,
                     return s.astype(t.dtype)
                 return jax.tree.map(one, rt), new_res
 
-            return compat.shard_map(
+            return jax.shard_map(
                 local_mix, mesh=mesh, in_specs=(param_specs, P(),
                                                 param_specs),
                 out_specs=(param_specs, param_specs),
@@ -476,7 +477,7 @@ def _make_weighted_psum_aggregate(aggregator, codec, mesh, param_specs,
                 return s.astype(t.dtype)
             return jax.tree.map(one, rt)
 
-        return compat.shard_map(
+        return jax.shard_map(
             local_mix, mesh=mesh, in_specs=(param_specs, P()),
             out_specs=param_specs, check_vma=False)(stacked, weights[0])
     return aggregate
@@ -939,7 +940,6 @@ class GraphGossip(Aggregator):
         # no all-gather, the local half stays exact, and the per-leg
         # weights are gathered from the traced matrix at the pod's index
         from jax.sharding import PartitionSpec as P
-        from repro.sharding import compat
 
         def aggregate(stacked, weights):
             _check_one_row_per_pod(self, stacked, mesh, axis)
@@ -960,7 +960,7 @@ class GraphGossip(Aggregator):
                     return acc.astype(t.dtype)
                 return jax.tree.map(one, local, rt)
 
-            return compat.shard_map(
+            return jax.shard_map(
                 local_mix, mesh=mesh, in_specs=(param_specs, P()),
                 out_specs=param_specs, check_vma=False)(stacked, weights)
         return aggregate
@@ -1026,7 +1026,6 @@ class RingGossip(GraphGossip):
         # neighbor row (one ppermute per leaf, f32 payloads, combinable by
         # XLA) — O(model) point-to-point traffic, no all-gather, and the
         # local half stays exact
-        from repro.sharding import compat
         K = mesh.shape[axis]
         perm = [(j, (j + 1) % K) for j in range(K)]
 
@@ -1044,7 +1043,7 @@ class RingGossip(GraphGossip):
                             + 0.5 * recv).astype(t.dtype)
                 return jax.tree.map(one, local, rt)
 
-            return compat.shard_map(
+            return jax.shard_map(
                 local_mix, mesh=mesh, in_specs=(param_specs,),
                 out_specs=param_specs, check_vma=False)(stacked)
         return aggregate
@@ -1151,7 +1150,6 @@ class D2Gossip(GraphGossip):
             return None
         perms, srcs = setup
         from jax.sharding import PartitionSpec as P
-        from repro.sharding import compat
 
         def aggregate(stacked, weights, corr):
             _check_one_row_per_pod(self, stacked, mesh, axis)
@@ -1183,7 +1181,7 @@ class D2Gossip(GraphGossip):
                     mixed_f, local)
                 return mixed, new_c
 
-            return compat.shard_map(
+            return jax.shard_map(
                 local_mix, mesh=mesh,
                 in_specs=(param_specs, P(), param_specs),
                 out_specs=(param_specs, param_specs),
@@ -1694,6 +1692,7 @@ class _FusedRunner:
         gated = self._gated
         i = state["round"]
         T_i = state["ctrl"].T
+        first_round = state["prev_avg"] is None
         # per-round host quantities are staged EXPLICITLY (device_put via
         # engine_mod.stage): an implicit transfer here — jnp.int32 on a
         # python scalar, numpy riding into the donated call — is exactly
@@ -1725,6 +1724,12 @@ class _FusedRunner:
             # and comes back in the aux dict (device-side, like new_avg)
             lead = ((state["params"], state["opt"], state["residual"])
                     if self._stateful else (state["params"], state["opt"]))
+            if not gated:
+                # Eq. 4 compares against the entry params inside the
+                # executable, so the previous shared model is dead here:
+                # release it before the dispatch (a whole model of device
+                # memory at published widths)
+                state["prev_avg"] = None
             if gated:
                 out_p, out_o, aux = self._round(
                     *lead, batches, *mask_args,
@@ -1807,7 +1812,7 @@ class _FusedRunner:
         synced = bool(sync_dev)
         if not synced:
             rel = float(div_dev)
-        elif state["prev_avg"] is None:
+        elif first_round:
             rel = float("inf")
         else:
             rel = float(rel_dev)
@@ -1857,7 +1862,7 @@ def register_sync_policy(name, factory):
     return factory
 
 
-def _leafwise_codec(block=DEFAULT_BLOCK, impl="ref", bits=8,
+def _leafwise_codec(block=DEFAULT_BLOCK, impl=None, bits=8,
                     error_feedback=False):
     """``bits=8`` without error feedback resolves to the LeafwiseInt8
     class so registry/back-compat isinstance pins keep holding."""
@@ -1867,7 +1872,7 @@ def _leafwise_codec(block=DEFAULT_BLOCK, impl="ref", bits=8,
                         error_feedback=error_feedback)
 
 
-def _flat_codec(block=DEFAULT_BLOCK, impl="ref", bits=8,
+def _flat_codec(block=DEFAULT_BLOCK, impl=None, bits=8,
                 error_feedback=False):
     if bits == 8 and not error_feedback:
         return FlatFusedInt8(block=block, impl=impl)
@@ -1875,7 +1880,7 @@ def _flat_codec(block=DEFAULT_BLOCK, impl="ref", bits=8,
                          error_feedback=error_feedback)
 
 
-register_codec("exact", lambda block=DEFAULT_BLOCK, impl="ref", bits=8,
+register_codec("exact", lambda block=DEFAULT_BLOCK, impl=None, bits=8,
                error_feedback=False: ExactF32())
 register_codec("none", CODECS["exact"])
 register_codec("leafwise", _leafwise_codec)
@@ -1932,7 +1937,7 @@ def _resolve(spec, registry, default, proto, kind, **kw):
                     f"{proto.__name__}; got {spec!r}")
 
 
-def get_codec(spec=None, *, block=DEFAULT_BLOCK, impl="ref", bits=8,
+def get_codec(spec=None, *, block=DEFAULT_BLOCK, impl=None, bits=8,
               error_feedback=False) -> WireCodec:
     """None | registry name | WireCodec instance -> WireCodec.
 

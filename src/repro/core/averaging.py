@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.sharding import compat
 
 
 def stack_participants(params, K: int):
@@ -81,7 +80,7 @@ def make_average_shard_map(mesh, param_specs, axis="pod"):
             return jnp.broadcast_to(s, t.shape).astype(t.dtype)
         return jax.tree.map(one, local)
 
-    return jax.jit(compat.shard_map(
+    return jax.jit(jax.shard_map(
         _avg, mesh=mesh, in_specs=(param_specs,), out_specs=param_specs,
         check_vma=False))
 

@@ -202,15 +202,17 @@ class CoLearner:
                    compress_fn: Callable | None = None,
                    engine: str = "python", fused_chunk: int = 32,
                    compress: str | None = None, compress_block: int = 256,
-                   compress_impl: str = "ref", aggregator=None):
+                   compress_impl: str | None = None, aggregator=None):
         """The pre-PR-3 flag surface, mapped onto strategy objects.
 
         engine="python"|"fused" (+ fused_chunk) -> round_engine;
         compress=None|"leafwise"|"fused" (+ compress_block/compress_impl)
         -> codec; compress_fn stays the low-level escape hatch (an opaque
         stacked->stacked wire transform, mutually exclusive with
-        compress="fused"). Behavior is flag-for-flag identical to the old
-        constructor; parity is asserted in tests/test_api.py.
+        compress="fused"); compress_impl=None runs the codec's kernels on a
+        TPU backend and the reference elsewhere. Behavior is flag-for-flag
+        identical to the old constructor; parity is asserted in
+        tests/test_api.py.
         """
         if engine not in ("python", "fused"):
             raise ValueError(f"unknown engine {engine!r}")

@@ -48,12 +48,15 @@ import jax.numpy as jnp
 from repro.kernels import ops as kops
 
 
-def quantize_roundtrip(tree, block=256, impl="ref", bits=8):
+def quantize_roundtrip(tree, block=256, impl=None, bits=8):
     """Simulate the compressed upload: quantize then dequantize every leaf.
 
     Leaves with fewer than ``block`` elements (and scalars) are returned
     unchanged — they go on the wire uncompressed (see ``compressed_bytes``).
+    ``impl=None`` is the kernel on a TPU backend, the reference elsewhere.
     """
+    impl = kops.resolve_impl(impl)
+
     def one(t):
         if t.ndim == 0 or t.size < block:
             return t
@@ -64,12 +67,14 @@ def quantize_roundtrip(tree, block=256, impl="ref", bits=8):
     return jax.tree.map(one, tree)
 
 
-def quantize_roundtrip_ef(tree, residual, block=256, impl="ref", bits=8):
+def quantize_roundtrip_ef(tree, residual, block=256, impl=None, bits=8):
     """Error-feedback leafwise roundtrip: quantize ``t + e`` per leaf and
     return ``(roundtripped tree, new residual tree)`` with
     ``e' = (t + e) - dequant``. Residual leaves are f32 mirrors of the
     params; bypassed leaves pass through unchanged with residual zero.
     """
+    impl = kops.resolve_impl(impl)
+
     def one(t, e):
         if t.ndim == 0 or t.size < block:
             return t, e
@@ -85,7 +90,7 @@ def quantize_roundtrip_ef(tree, residual, block=256, impl="ref", bits=8):
             jax.tree.unflatten(treedef, [o[1] for o in out]))
 
 
-def make_compress_fn(block=256, impl="ref", bits=8):
+def make_compress_fn(block=256, impl=None, bits=8):
     """compress_fn for CoLearner: emulates the quantized wire format."""
     def fn(stacked):
         return quantize_roundtrip(stacked, block=block, impl=impl, bits=bits)

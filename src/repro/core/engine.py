@@ -277,7 +277,7 @@ def _make_epoch_scan(epoch_fn, lr_fn, masked=False, live=False):
     return scan_epochs
 
 
-def make_fused_compressed_average(*, block=256, impl="ref", bits=8,
+def make_fused_compressed_average(*, block=256, impl=None, bits=8,
                                   mesh=None, axis="pod", weighted=False,
                                   stateful=False):
     """Eq. 2 fast path: quantized wire emulation + averaging as ONE pass.
@@ -321,8 +321,10 @@ def make_fused_compressed_average(*, block=256, impl="ref", bits=8,
     still ONE psum with each pod's residual staying resident on that pod.
 
     The layout is recomputed per trace from static shapes only (free); the
-    same tree structure always yields the same wire layout.
+    same tree structure always yields the same wire layout. ``impl=None``
+    runs the kernels on a TPU backend and the reference elsewhere.
     """
+    impl = kops.resolve_impl(impl)
     if mesh is None:
         if stateful:
             if weighted:
@@ -369,7 +371,6 @@ def make_fused_compressed_average(*, block=256, impl="ref", bits=8,
         return average
 
     from repro.kernels.quantize import unpack_codes
-    from repro.sharding import compat
     K = mesh.shape[axis]
 
     def _local_dequant(q, scale):
@@ -394,7 +395,7 @@ def make_fused_compressed_average(*, block=256, impl="ref", bits=8,
                     s = jax.lax.psum(w[k].astype(jnp.float32) * dq, axis)
                     return s, y - dq
 
-                avg, new_res = compat.shard_map(
+                avg, new_res = jax.shard_map(
                     local_avg, mesh=mesh,
                     in_specs=(P(axis, None), P(), P(axis, None)),
                     out_specs=(P(axis, None), P(axis, None)),
@@ -415,7 +416,7 @@ def make_fused_compressed_average(*, block=256, impl="ref", bits=8,
                 mean = jax.lax.psum(dq, axis) / K
                 return mean, y - dq
 
-            avg, new_res = compat.shard_map(
+            avg, new_res = jax.shard_map(
                 local_avg, mesh=mesh,
                 in_specs=(P(axis, None), P(axis, None)),
                 out_specs=(P(axis, None), P(axis, None)),
@@ -436,10 +437,10 @@ def make_fused_compressed_average(*, block=256, impl="ref", bits=8,
                 s = jax.lax.psum(w[k].astype(jnp.float32) * dq, axis)
                 return s.reshape(1, -1)[:, :layout.n_pad]
 
-            avg = compat.shard_map(local_avg, mesh=mesh,
-                                   in_specs=(P(axis, None), P()),
-                                   out_specs=P(axis, None),
-                                   check_vma=False)(buf, wrow)
+            avg = jax.shard_map(local_avg, mesh=mesh,
+                                in_specs=(P(axis, None), P()),
+                                out_specs=P(axis, None),
+                                check_vma=False)(buf, wrow)
             return flatbuf.unflatten(avg, layout)
         return average_w
 
@@ -454,10 +455,10 @@ def make_fused_compressed_average(*, block=256, impl="ref", bits=8,
             mean = jax.lax.psum(dq, axis) / K
             return mean.reshape(1, -1)[:, :layout.n_pad]
 
-        avg = compat.shard_map(local_avg, mesh=mesh,
-                               in_specs=(P(axis, None),),
-                               out_specs=P(axis, None),
-                               check_vma=False)(buf)
+        avg = jax.shard_map(local_avg, mesh=mesh,
+                            in_specs=(P(axis, None),),
+                            out_specs=P(axis, None),
+                            check_vma=False)(buf)
         return flatbuf.unflatten(avg, layout)
     return average
 

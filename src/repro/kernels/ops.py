@@ -1,12 +1,17 @@
 """Jit'd dispatch wrappers for the Pallas kernels.
 
 impl semantics:
-  * "ref"     — pure-jnp oracle (default on CPU; also what the dry-run
-                lowers, since Mosaic custom-calls need a TPU backend);
-  * "pallas"  — the real kernel; automatically falls back to interpret
-                mode when the backend is not TPU (bit-accurate kernel-body
-                execution in Python — how tests validate the kernels here);
-  * "interpret" — force interpret mode explicitly.
+  * "ref"     — pure-jnp oracle: the CPU path and the test oracle (also
+                what the dry-run lowers, since Mosaic custom-calls need a
+                TPU backend);
+  * "pallas"  — the compiled TPU kernel, always: on a backend that cannot
+                compile it the call fails instead of running something else;
+  * "interpret" — the kernel body in Pallas interpret mode (bit-accurate
+                kernel-body execution on the CPU — how tests validate the
+                kernels here).
+
+Callers that take ``impl=None`` resolve it with ``resolve_impl``: the
+kernel on a TPU backend, the reference elsewhere.
 """
 from __future__ import annotations
 
@@ -22,10 +27,15 @@ from repro.kernels import ref as _ref
 from repro.kernels import selective_scan as _ss
 
 
+def resolve_impl(impl=None):
+    """``impl`` as given; None is "pallas" on a TPU backend, else "ref"."""
+    if impl is not None:
+        return impl
+    return "pallas" if jax.default_backend() == "tpu" else "ref"
+
+
 def _interp(impl):
-    if impl == "interpret":
-        return True
-    return jax.default_backend() != "tpu"
+    return impl == "interpret"
 
 
 def flash_attention(q, k, v, *, n_kv_heads, window=0, softmax_scale=None,

@@ -27,6 +27,7 @@ from repro.core import api
 from repro.core.colearn import CoLearner
 from repro.data.stream import ShardStream, get_drift
 from repro.data.synthetic import lm_examples
+from repro.launch import compile_cache
 from repro.models import transformer as tr
 from repro.serving import ModelBank, ServeLoop
 
@@ -42,6 +43,7 @@ def drift_from_flags(args):
 
 
 def main(argv=None):
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="internlm2-1.8b")
     ap.add_argument("--participants", type=int, default=3)

@@ -31,7 +31,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import ARCH_IDS, INPUT_SHAPES, get_config
 from repro.launch import analytic, steps as steps_mod
 from repro.launch.mesh import make_production_mesh
-from repro.sharding import compat, specs as sp
+from repro.sharding import specs as sp
 
 DTYPE_BYTES = {"f32": 4, "bf16": 2, "f16": 2, "s32": 4, "u32": 4, "s8": 1,
                "u8": 1, "pred": 1, "f64": 8, "s64": 8, "u64": 8, "s16": 2,
@@ -213,7 +213,7 @@ def build(cfg, shape, mesh, multi_pod, variant, lowering):
 
 def _compile(cfg, shape, mesh, multi_pod, variant, lowering):
     fn, args = build(cfg, shape, mesh, multi_pod, variant, lowering)
-    with compat.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = fn.lower(*args).compile()
     return compiled
 

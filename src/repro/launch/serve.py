@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_smoke_config
+from repro.launch import compile_cache
 from repro.models import transformer as tr
 from repro.serving import ServeLoop
 
@@ -27,6 +28,7 @@ def prefill_into_cache(loop: ServeLoop, tokens):
 
 
 def main(argv=None):
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="internlm2-1.8b")
     ap.add_argument("--batch", type=int, default=4)

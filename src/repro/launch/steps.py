@@ -139,7 +139,7 @@ def make_fused_round_step(cfg, ccfg, *, optimizer="sgd", lowering="scan",
                           schedule=None, round_index=0,
                           expose_schedule_args=False, masked=False,
                           live=False, compress=None, compress_block=256,
-                          compress_impl="ref", codec_bits=8,
+                          compress_impl=None, codec_bits=8,
                           error_feedback=False):
     """Pod-path fused round: the whole communication round as one program.
 
@@ -194,6 +194,9 @@ def make_fused_round_step(cfg, ccfg, *, optimizer="sgd", lowering="scan",
     live set (``Membership.live_mask()`` feeds both the row and
     ``aggregator.mixing_matrix(..., live=...)``). Membership changes ride
     in as data — the compiled executable is reused across churn.
+
+    ``compress_impl`` picks the quantizing codecs' kernels: None is the
+    compiled Pallas kernel on a TPU backend and the jnp reference elsewhere.
 
     ``codec_bits``/``error_feedback`` parameterize the quantizing codecs
     (registry-name or legacy ``compress=`` spellings): payload bit width

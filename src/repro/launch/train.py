@@ -7,7 +7,11 @@ per-round losses, the Eq.4 controller decisions, and communication volume.
 Usage:
   PYTHONPATH=src python -m repro.launch.train --arch internlm2-1.8b \
       --participants 5 --rounds 6 --t0 2 --steps-per-epoch 8
-  ... --vanilla     # centralized baseline (same total data, K=1)
+  ... --widths published --n-layers 2   # published widths, depth cut to 2
+
+The model is the arch's reduced smoke config by default; ``--widths
+published`` loads its published config instead, and ``--n-layers`` keeps
+only the first N layers of either (``configs.cut_depth``).
 
 Round strategy (see repro.core.api): --codec picks the wire format of the
 uploads (exact f32 | leafwise int8 | fused flat-buffer), --aggregator picks
@@ -47,13 +51,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint.io import save_round_state
-from repro.configs import get_smoke_config
+from repro.configs import cut_depth, get_config, get_smoke_config
 from repro.configs.base import CoLearnConfig
 from repro.core import api
 from repro.core.colearn import CoLearner
 from repro.data import partition as part_mod
 from repro.data.pipeline import ParticipantData
 from repro.data.synthetic import lm_examples
+from repro.launch import compile_cache
 from repro.models import transformer as tr
 
 
@@ -82,7 +87,7 @@ def build_data(cfg, K, batch_size, seq_len, n_examples, seed=0,
 _eval_loss_step = jax.jit(tr.loss_fn, static_argnums=(1,))
 
 
-def eval_loss(params, cfg, x, y, batch=64):
+def eval_loss(params, cfg, x, y, batch):
     tot, n = 0.0, 0
     for i in range(0, len(x) - batch + 1, batch):
         b = {"tokens": jnp.asarray(x[i:i + batch]),
@@ -93,9 +98,20 @@ def eval_loss(params, cfg, x, y, batch=64):
     return tot / max(n, 1)
 
 
-def main(argv=None):
+def main(argv=None, on_round_end=None):
+    """Run the CLI. ``on_round_end(learner, state, seconds)``, when given,
+    fires after each round with the round's wall time (eval excluded) —
+    how an in-process caller such as ``chip_smoke.py`` reads the run."""
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="internlm2-1.8b")
+    ap.add_argument("--widths", default="smoke",
+                    choices=["smoke", "published"],
+                    help="smoke = the arch's reduced CPU-test config; "
+                         "published = its published widths")
+    ap.add_argument("--n-layers", type=int, default=0,
+                    help="keep only the first N layers (0 = the config's "
+                         "own depth)")
     ap.add_argument("--participants", type=int, default=5)
     ap.add_argument("--rounds", type=int, default=5)
     ap.add_argument("--t0", type=int, default=2)
@@ -281,7 +297,13 @@ def main(argv=None):
     if args.naive_membership and churn is None:
         ap.error("--naive-membership requires --churn")
 
-    cfg = get_smoke_config(args.arch)
+    cfg = (get_config(args.arch) if args.widths == "published"
+           else get_smoke_config(args.arch))
+    if args.n_layers:
+        try:
+            cfg = cut_depth(cfg, args.n_layers)
+        except ValueError as e:
+            ap.error(str(e))
     K = k_max
     ccfg = CoLearnConfig(
         n_participants=K, T0=args.t0, eta0=args.eta0, epsilon=args.epsilon,
@@ -346,7 +368,11 @@ def main(argv=None):
                         batch_mask=batch_mask, churn=churn,
                         liveness_aware=not args.naive_membership)
     params = tr.init_params(jax.random.PRNGKey(args.seed), cfg, jnp.float32)
+    n_params = tr.count_params(params)
     state = learner.init(params)
+    # the stacked copies are all the learner needs; at published widths the
+    # unstacked tree is a whole model's worth of device memory
+    del params
     shard_s = (f" shards={list(data.sizes)}" if args.partition != "iid"
                or data.ragged else "")
     if churn is not None:
@@ -354,24 +380,31 @@ def main(argv=None):
                     + (f" k_max={k_max}" if args.k_max else "")
                     + (" naive" if args.naive_membership else ""))
     print(f"co-learning {cfg.name}: K={K} params="
-          f"{tr.count_params(params):,} rounds={args.rounds} T0={args.t0} "
+          f"{n_params:,} rounds={args.rounds} T0={args.t0} "
           f"{learner.schedule.name}+{learner.sync_policy.name} "
           f"engine={args.engine} codec={learner.codec.name} "
           f"aggregator={learner.aggregator.name} "
           f"partition={args.partition}{shard_s}", flush=True)
 
+    def epoch_batches(round_i, epoch_j):
+        bx, by = data.epoch_batches(round_i, epoch_j)
+        if args.steps_per_epoch:
+            bx, by = bx[:, :args.steps_per_epoch], by[:, :args.steps_per_epoch]
+        return (jnp.asarray(bx), jnp.asarray(by))
+
     for _ in range(args.rounds):
-        t0 = time.time()
-
-        def epoch_batches(round_i, epoch_j):
-            bx, by = data.epoch_batches(round_i, epoch_j)
-            if args.steps_per_epoch:
-                bx, by = bx[:, :args.steps_per_epoch], by[:, :args.steps_per_epoch]
-            return (jnp.asarray(bx), jnp.asarray(by))
-
+        t0 = time.perf_counter()
+        # the round ends in its host fetch of the losses, so the clock
+        # covers the device work
         state = learner.run_round(state, epoch_batches)
+        round_s = time.perf_counter() - t0
+        if on_round_end is not None:
+            on_round_end(learner, state, round_s)
         log = state["log"][-1]
-        ev = eval_loss(learner.shared_model(state), cfg, ex, ey)
+        t_eval = time.perf_counter()
+        ev = eval_loss(learner.shared_model(state), cfg, ex, ey,
+                       args.batch_size)
+        eval_s = time.perf_counter() - t_eval
         sync_s = "" if log.synced else " SKIP(sync)"
         if churn is not None:
             sync_s += f" live={log.live}/{K}"
@@ -379,7 +412,8 @@ def main(argv=None):
               f"{log.lr_last:.4f} rel_dw={log.rel_change:.4f} "
               f"local_loss={np.mean(log.local_losses):.4f} eval={ev:.4f} "
               f"comm={log.comm_bytes/2**20:.1f}MiB next_T={state['ctrl'].T}"
-              f"{sync_s} ({time.time()-t0:.1f}s)", flush=True)
+              f"{sync_s} (round {round_s:.1f}s, eval {eval_s:.1f}s)",
+              flush=True)
 
     if args.checkpoint:
         save_round_state(args.checkpoint, state)

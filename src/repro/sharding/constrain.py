@@ -61,11 +61,8 @@ def _resolve(dim, ax, mesh, axes):
 
 
 def constrain(x, spec):
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        axes = set(mesh.axis_names)
-    except Exception:
-        return x
+    mesh = jax.sharding.get_abstract_mesh()
+    axes = set(mesh.axis_names)
     if not axes:
         return x
     out = []

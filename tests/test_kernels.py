@@ -309,3 +309,22 @@ def test_check_bits_rejects_unknown_widths():
     for bad in (2, 3, 16, 0):
         with pytest.raises(ValueError):
             check_bits(bad)
+
+
+# ---------------------------------------------------------------------------
+# impl selection: "pallas" is the compiled kernel or an error, never a
+# silent interpret-mode or reference run
+# ---------------------------------------------------------------------------
+def test_pallas_impl_raises_on_cpu_instead_of_interpreting():
+    buf = jax.random.normal(KEY, (2, 4096), jnp.float32)
+    with pytest.raises(ValueError, match="interpret mode"):
+        ops.quant_avg_dequant(buf, impl="pallas")
+
+
+def test_default_impl_is_the_reference_off_tpu():
+    from repro.core import api
+    assert jax.default_backend() == "cpu"
+    assert ops.resolve_impl() == "ref"
+    assert api.FlatFusedInt8().impl == "ref"
+    assert api.get_codec("leafwise", bits=4).impl == "ref"
+    assert api.FlatFusedIntN(impl="interpret").impl == "interpret"

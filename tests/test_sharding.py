@@ -20,7 +20,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_smoke_config
 from repro.launch import steps as steps_mod
 from repro.launch.mesh import make_sim_mesh
-from repro.sharding import compat, specs as sp
+from repro.sharding import specs as sp
 from repro.core import averaging
 from repro.models import transformer as tr
 
@@ -36,7 +36,7 @@ bsh = sp.named(mesh, sp.batch_specs(cfg, mesh, "train"))
 step = steps_mod.make_train_step(cfg, lr=0.01)
 batch = {"tokens": jnp.zeros((8, 16), jnp.int32),
          "labels": jnp.ones((8, 16), jnp.int32)}
-with compat.use_mesh(mesh):
+with jax.set_mesh(mesh):
     fn = jax.jit(step, in_shardings=(psh, bsh),
                  out_shardings=(psh, NamedSharding(mesh, P())))
     new_params, loss = fn(params, batch)
@@ -53,7 +53,7 @@ cbsh = sp.named(mesh, sp.batch_specs(cfg, mesh, "train", participant=True))
 cbatch = {"tokens": jnp.zeros((K, 4, 16), jnp.int32),
           "labels": jnp.ones((K, 4, 16), jnp.int32)}
 cstep = steps_mod.make_colearn_train_step(cfg, lr=0.01)
-with compat.use_mesh(mesh):
+with jax.set_mesh(mesh):
     cfn = jax.jit(cstep, in_shardings=(spsh, cbsh))
     new_stacked, losses = cfn(stacked, cbatch)
 out["colearn_losses"] = [float(x) for x in losses]
@@ -82,7 +82,7 @@ round_fn = steps_mod.make_fused_round_step(
     param_specs=sp.param_specs(spshapes, cfg, mesh, participant=True))
 rbatch = {"tokens": jnp.zeros((2, K, 1, 4, 16), jnp.int32),
           "labels": jnp.ones((2, K, 1, 4, 16), jnp.int32)}
-with compat.use_mesh(mesh):
+with jax.set_mesh(mesh):
     averaged, _, aux = round_fn(stacked, (), rbatch, jnp.int32(0))
 out["fused_round_losses_finite"] = bool(jnp.isfinite(aux["losses"]).all())
 out["fused_round_rel_finite"] = bool(jnp.isfinite(aux["rel"]))
@@ -97,7 +97,7 @@ out["fused_round_slots_equal"] = max(
 from repro.core import api
 flat_avg = api.FullAverage().make_aggregate_fn(
     api.FlatFusedInt8(impl="ref"), mesh=mesh)
-with compat.use_mesh(mesh):
+with jax.set_mesh(mesh):
     favg = jax.jit(flat_avg)(new_stacked)
 errs, bounds = [], []
 for f, e, s in zip(jax.tree.leaves(favg), jax.tree.leaves(avg_p),
@@ -115,7 +115,7 @@ out["flat_avg_slots_equal"] = max(
 leaf_avg = api.FullAverage().make_aggregate_fn(
     api.LeafwiseInt8(impl="ref"), mesh=mesh,
     param_specs=sp.param_specs(spshapes, cfg, mesh, participant=True))
-with compat.use_mesh(mesh):
+with jax.set_mesh(mesh):
     lavg = jax.jit(leaf_avg)(new_stacked)
 errs = [float(jnp.abs(f.astype(jnp.float32) - e.astype(jnp.float32)).max())
         for f, e in zip(jax.tree.leaves(lavg), jax.tree.leaves(avg_p))]
@@ -134,7 +134,7 @@ for nm, agg in (("partial", api.PartialParticipation(m=2, seed=0)),
     mesh_fn = agg.make_aggregate_fn(api.ExactF32(), mesh=mesh,
                                     param_specs=pspecs_part)
     host_fn = agg.make_aggregate_fn(api.ExactF32())
-    with compat.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         got = jax.jit(mesh_fn)(new_stacked, W)
     want = host_fn(new_stacked, W)
     out[f"{nm}_mesh_matches_host"] = max(
@@ -152,7 +152,7 @@ for nm, agg in (("graph_hypercube", api.GraphGossip("hypercube")),
                                           pspecs_part, "pod")
     out[f"{nm}_sparse_path_engaged"] = mesh_fn is not None
     host_fn = agg._make_host_aggregate_fn(api.ExactF32())
-    with compat.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         got = jax.jit(mesh_fn)(new_stacked, W)
     want = host_fn(new_stacked, W)
     out[f"{nm}_mesh_matches_host"] = max(
@@ -168,7 +168,7 @@ d2_mesh = d2._make_mesh_aggregate_fn(api.ExactF32(), mesh,
                                      pspecs_part, "pod")
 out["d2_sparse_path_engaged"] = d2_mesh is not None
 d2_host = d2._make_host_aggregate_fn(api.ExactF32())
-with compat.use_mesh(mesh):
+with jax.set_mesh(mesh):
     gmix, gcorr = jax.jit(d2_mesh)(new_stacked, W, corr0)
 wmix, wcorr = d2_host(new_stacked, W, corr0)
 out["d2_mesh_matches_host"] = max(
@@ -186,7 +186,7 @@ W = jnp.asarray(wagg.mixing_matrix(0, K))
 wmesh = wagg.make_aggregate_fn(api.ExactF32(), mesh=mesh,
                                param_specs=pspecs_part)
 whost = wagg.make_aggregate_fn(api.ExactF32())
-with compat.use_mesh(mesh):
+with jax.set_mesh(mesh):
     wgot = jax.jit(wmesh)(new_stacked, W)
 wwant = whost(new_stacked, W)
 out["weighted_full_mesh_matches_host"] = max(
@@ -195,7 +195,7 @@ out["weighted_full_mesh_matches_host"] = max(
 
 wflat = api.FlatFusedInt8(impl="ref").make_fused_mean(mesh=mesh,
                                                       weighted=True)
-with compat.use_mesh(mesh):
+with jax.set_mesh(mesh):
     wfgot = jax.jit(wflat)(new_stacked, W[0])
 out["weighted_flat_mesh_within_bound"] = all(
     float(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32)).max()) <= bd
@@ -208,7 +208,7 @@ round_fn_m = steps_mod.make_fused_round_step(
 rbatch_m = {"tokens": jnp.zeros((2, K, 2, 4, 16), jnp.int32),
             "labels": jnp.ones((2, K, 2, 4, 16), jnp.int32)}
 bmask = jnp.asarray(np.array([[True, True], [True, False]]))
-with compat.use_mesh(mesh):
+with jax.set_mesh(mesh):
     averaged_m, _, aux_m = round_fn_m(stacked, (), rbatch_m, bmask,
                                       jnp.int32(0), W)
 out["masked_round_losses_finite"] = bool(jnp.isfinite(aux_m["losses"]).all())
@@ -222,7 +222,7 @@ out["masked_round_slots_equal"] = max(
 #     the mesh with each pod's residual resident on that pod
 gen8 = api.FullAverage().make_aggregate_fn(
     api.FlatFusedIntN(bits=8, impl="ref"), mesh=mesh)
-with compat.use_mesh(mesh):
+with jax.set_mesh(mesh):
     favg_gen = jax.jit(gen8)(new_stacked)
 out["intn_bits8_pod_bit_identical"] = max(
     float(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32)).max())
@@ -230,7 +230,7 @@ out["intn_bits8_pod_bit_identical"] = max(
 lgen8 = api.FullAverage().make_aggregate_fn(
     api.LeafwiseIntN(bits=8, impl="ref"), mesh=mesh,
     param_specs=pspecs_part)
-with compat.use_mesh(mesh):
+with jax.set_mesh(mesh):
     lavg_gen = jax.jit(lgen8)(new_stacked)
 out["leafwise_bits8_pod_bit_identical"] = max(
     float(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32)).max())
@@ -240,7 +240,7 @@ ef_codec = api.FlatFusedIntN(bits=4, error_feedback=True, impl="ref")
 res0 = ef_codec.init_state(new_stacked)
 ef_mesh = api.FullAverage().make_aggregate_fn(ef_codec, mesh=mesh)
 ef_host = api.FullAverage().make_aggregate_fn(ef_codec)
-with compat.use_mesh(mesh):
+with jax.set_mesh(mesh):
     mixed_m, res_m = jax.jit(ef_mesh)(new_stacked, None, res0)
 mixed_h, res_h = ef_host(new_stacked, None, res0)
 out["ef_int4_pod_matches_host"] = max(
@@ -253,7 +253,7 @@ out["ef_int4_pod_residual_nonzero"] = float(jnp.abs(res_m).max()) > 0.0
 round_fn_ef = steps_mod.make_fused_round_step(
     cfg, ccfg, mesh=mesh, codec="fused", codec_bits=4, error_feedback=True,
     param_specs=pspecs_part)
-with compat.use_mesh(mesh):
+with jax.set_mesh(mesh):
     averaged_ef, _, aux_ef = round_fn_ef(stacked, (), res0, rbatch,
                                          jnp.int32(0))
 out["ef_round_losses_finite"] = bool(jnp.isfinite(aux_ef["losses"]).all())
@@ -268,7 +268,7 @@ cache = tr.init_cache(cfg, 8, 16, jnp.float32)
 csh = sp.named(mesh, sp.cache_specs(
     jax.tree.map(lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype), cache),
     mesh, 8))
-with compat.use_mesh(mesh):
+with jax.set_mesh(mesh):
     sfn = jax.jit(steps_mod.make_serve_step(cfg),
                   in_shardings=(psh, csh, NamedSharding(mesh, P()),
                                 NamedSharding(mesh, P())))

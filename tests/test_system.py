@@ -4,7 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs import get_smoke_config
+from repro.configs import ARCH_IDS, cut_depth, get_config, get_smoke_config
 from repro.configs.base import CoLearnConfig
 from repro.core.colearn import CoLearner
 from repro.core.compression import make_compress_fn
@@ -72,6 +72,47 @@ def test_train_driver_cli_runs():
                "--batch-size", "4", "--seq-len", "16",
                "--steps-per-epoch", "2"])
     assert rc == 0
+
+
+def test_train_driver_builds_published_widths(monkeypatch):
+    """--widths published --n-layers N loads the published config with its
+    depth cut; the run stops at parameter init (full widths are too large
+    for a CPU test)."""
+    from repro.launch import train
+
+    class Built(Exception):
+        pass
+
+    def stop(key, cfg, dtype):
+        raise Built(cfg)
+
+    monkeypatch.setattr(train.tr, "init_params", stop)
+    with pytest.raises(Built) as e:
+        train.main(["--arch", "internlm2-1.8b", "--widths", "published",
+                    "--n-layers", "1", "--participants", "2",
+                    "--n-examples", "16", "--batch-size", "4",
+                    "--seq-len", "16"])
+    cfg = e.value.args[0]
+    assert (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
+            cfg.d_ff, cfg.vocab_size) == (1, 2048, 16, 8, 8192, 92_544)
+    assert cfg.layer_kinds() == ["gqa:dense"]
+
+
+def test_train_driver_rejects_depth_beyond_config():
+    from repro.launch.train import main
+    with pytest.raises(SystemExit) as e:
+        main(["--n-layers", "3"])                 # the smoke config has 2
+    assert e.value.code == 2
+
+
+@pytest.mark.parametrize("arch", ARCH_IDS)
+def test_cut_depth_keeps_widths_and_layer_order(arch):
+    full = get_config(arch)
+    for n in (1, 2, 3):
+        cut = cut_depth(full, n)
+        assert cut.layer_kinds() == full.layer_kinds()[:n]
+        assert cut.with_(n_layers=full.n_layers,
+                         segments=full.segments) == full
 
 
 def test_train_driver_cli_rejects_codec_plus_compress():
