@@ -87,6 +87,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.analysis import spans
 from repro.core import averaging, compression, engine as engine_mod, flatbuf
 from repro.core import schedule as sched_mod
 from repro.core.schedule import (LR_COS_ROUND, LR_EXP_GLOBAL, LR_EXP_ROUND,
@@ -1554,39 +1555,49 @@ class _PythonRunner:
         self.learner = learner
         self._stateful = getattr(learner, "_round_stateful",
                                  getattr(learner.codec, "stateful", False))
-        self._jit_agg = jax.jit(learner._aggregate_fn)
+        self._jit_agg = jax.jit(engine_mod.as_aggregate_fn(
+            learner._aggregate_fn))
 
     def run_round(self, state, epoch_batches_fn):
+        """The reference loop: per epoch, the batches (``colearn.input``),
+        one jitted epoch (``colearn.dispatch``) and its loss fetch
+        (``colearn.fetch``); then the aggregate, whose Eq. 4 metric and
+        divergence are fetched too."""
         learner = self.learner
         policy = learner.sync_policy
         i = state["round"]
         T_i = state["ctrl"].T
         ge0 = state["global_epoch"]
-        total = learner.epochs_budget(state)
-        sync_ref = learner._sync_ref(state)
-        mask = learner.batch_mask
-        # elastic membership: the liveness row rides into the jitted epoch
-        # as traced data (None on the static path — bit-identical)
-        live_np = learner._live_np(state)
-        live_row = (None if live_np is None
-                    else engine_mod.stage(live_np, np.float32))
+        with spans.span("colearn.dispatch"):
+            total = learner.epochs_budget(state)
+            sync_ref = learner._sync_ref(state)
+            mask = learner.batch_mask
+            # elastic membership: the liveness row rides into the jitted
+            # epoch as traced data (None on the static path — bit-identical)
+            live_np = learner._live_np(state)
+            live_row = (None if live_np is None
+                        else engine_mod.stage(live_np, np.float32))
         lrs, losses = [], []
         for j in range(T_i):
-            lr = float(learner.schedule.lr(i, j, T_i, ge0 + j, total))
-            lrs.append(lr)
-            batches = epoch_batches_fn(i, j)
-            args = (batches, lr)
-            if mask is not None:
-                args += (mask,)
-            if live_row is not None:
-                args += (live_row,)
-            params, opt, l = learner._jit_epoch(
-                state["params"], state["opt"], *args)
-            state["params"], state["opt"] = params, opt
-            losses.append(jax.device_get(l))
+            with spans.span("colearn.input"):
+                batches = epoch_batches_fn(i, j)
+            with spans.span("colearn.dispatch"):
+                lr = float(learner.schedule.lr(i, j, T_i, ge0 + j, total))
+                lrs.append(lr)
+                args = (batches, lr)
+                if mask is not None:
+                    args += (mask,)
+                if live_row is not None:
+                    args += (live_row,)
+                params, opt, l = learner._jit_epoch(
+                    state["params"], state["opt"], *args)
+                state["params"], state["opt"] = params, opt
+            with spans.span("colearn.fetch"):
+                losses.append(jax.device_get(l))
 
         if policy.divergence_gated:
-            div = sched_mod.divergence(state["params"], sync_ref, live_np)
+            with spans.span("colearn.fetch"):
+                div = sched_mod.divergence(state["params"], sync_ref, live_np)
             if _gate_accepts_delta(policy):
                 synced = bool(policy.should_sync(
                     div, i, delta=learner._round_delta(state)))
@@ -1600,29 +1611,32 @@ class _PythonRunner:
             # aggregate (Eq. 2 / partial / gossip) over the codec's wire;
             # a stateful codec (error feedback) threads the residual in
             # and out of the same jitted aggregate
-            if self._stateful:
-                averaged, new_res = self._jit_agg(
-                    state["params"], learner.round_weights(i, state),
-                    state["residual"])
-            else:
-                averaged = self._jit_agg(state["params"],
-                                         learner.round_weights(i, state))
-                new_res = None
-            k0 = 0 if live_np is None else int(np.argmax(live_np))
-            new_avg = averaging.unstack_participant(averaged, k0)
-            rel = (float("inf") if state["prev_avg"] is None
-                   else relative_change(new_avg, state["prev_avg"]))
-            fresh_opt = jax.vmap(learner.opt.init)(averaged)
-            if live_row is not None:
-                # dead rows: identity carry — no download, own opt kept,
-                # and (stateful) their residual memory is frozen too
-                averaged = engine_mod.select_live(live_row, averaged,
-                                                  state["params"])
-                fresh_opt = engine_mod.select_live(live_row, fresh_opt,
-                                                   state["opt"])
+            with spans.span("colearn.dispatch"):
                 if self._stateful:
-                    new_res = engine_mod.select_live(live_row, new_res,
-                                                     state["residual"])
+                    averaged, new_res = self._jit_agg(
+                        state["params"], learner.round_weights(i, state),
+                        state["residual"])
+                else:
+                    averaged = self._jit_agg(state["params"],
+                                             learner.round_weights(i, state))
+                    new_res = None
+                k0 = 0 if live_np is None else int(np.argmax(live_np))
+                new_avg = averaging.unstack_participant(averaged, k0)
+            with spans.span("colearn.fetch"):
+                rel = (float("inf") if state["prev_avg"] is None
+                       else relative_change(new_avg, state["prev_avg"]))
+            with spans.span("colearn.dispatch"):
+                fresh_opt = jax.vmap(learner.opt.init)(averaged)
+                if live_row is not None:
+                    # dead rows: identity carry — no download, own opt
+                    # kept, and (stateful) their residual memory is frozen
+                    averaged = engine_mod.select_live(live_row, averaged,
+                                                      state["params"])
+                    fresh_opt = engine_mod.select_live(live_row, fresh_opt,
+                                                       state["opt"])
+                    if self._stateful:
+                        new_res = engine_mod.select_live(
+                            live_row, new_res, state["residual"])
         else:
             # quiet round (Kamp): keep local params AND optimizer state,
             # reference unchanged, nothing crosses the wire (the residual
@@ -1693,122 +1707,135 @@ class _FusedRunner:
         i = state["round"]
         T_i = state["ctrl"].T
         first_round = state["prev_avg"] is None
-        # per-round host quantities are staged EXPLICITLY (device_put via
-        # engine_mod.stage): an implicit transfer here — jnp.int32 on a
-        # python scalar, numpy riding into the donated call — is exactly
-        # what guards.no_transfer() pins the round loop against
-        ge0 = engine_mod.stage(state["global_epoch"], np.int32)
-        sched = learner.schedule.device_round_params(i)
-        total = engine_mod.stage(learner.epochs_budget(state), np.int32)
-        agg_w = learner.round_weights(i, state)
-        if gated:
-            sync_ref = learner._sync_ref(state)
-            delta = engine_mod.stage(learner._round_delta(state),
-                                     np.float32)
-        div_dev, sync_dev = None, True
-        # the ragged-shard validity mask rides in traced right after the
-        # staged batches (absent entirely on the unmasked executables);
-        # the liveness row (elastic membership) follows the same way
-        mask_args = (learner.batch_mask,) if self._masked else ()
-        live_np = learner._live_np(state)
-        if self._live:
-            live_row = engine_mod.stage(live_np, np.float32)
-            mask_args = mask_args + (live_row,)
-        # state["params"]/["opt"] are reassigned immediately after every
-        # donating call below, so an exception mid-round (e.g. from
-        # epoch_batches_fn) can never leave state holding deleted buffers.
-        if T_i <= self.chunk:
-            batches = engine_mod.stack_epoch_batches(
-                [epoch_batches_fn(i, j) for j in range(T_i)])
-            # stateful codec: the residual rides in right after opt_state
-            # and comes back in the aux dict (device-side, like new_avg)
-            lead = ((state["params"], state["opt"], state["residual"])
-                    if self._stateful else (state["params"], state["opt"]))
-            if not gated:
-                # Eq. 4 compares against the entry params inside the
-                # executable, so the previous shared model is dead here:
-                # release it before the dispatch (a whole model of device
-                # memory at published widths)
-                state["prev_avg"] = None
+        single = T_i <= self.chunk
+        # batches are staged before anything is donated, so an exception
+        # in epoch_batches_fn leaves state untouched
+        if single:
+            with spans.span("colearn.input"):
+                batches = engine_mod.stack_epoch_batches(
+                    [epoch_batches_fn(i, j) for j in range(T_i)])
+        with spans.span("colearn.dispatch"):
+            # per-round host quantities are staged EXPLICITLY (device_put
+            # via engine_mod.stage): an implicit transfer here — jnp.int32
+            # on a python scalar, numpy riding into the donated call — is
+            # exactly what guards.no_transfer() pins the round loop against
+            ge0 = engine_mod.stage(state["global_epoch"], np.int32)
+            sched = learner.schedule.device_round_params(i)
+            total = engine_mod.stage(learner.epochs_budget(state), np.int32)
+            agg_w = learner.round_weights(i, state)
             if gated:
-                out_p, out_o, aux = self._round(
-                    *lead, batches, *mask_args,
-                    ge0, sched, total, sync_ref, delta, agg_w)
-            else:
-                out_p, out_o, aux = self._round(
-                    *lead, batches, *mask_args,
-                    ge0, sched, total, agg_w)
-            state["params"], state["opt"] = out_p, out_o
-            if self._stateful:
-                state["residual"] = aux["residual"]
-            new_avg = aux["new_avg"]
-            # the round's single host sync (scalars/loss curves only — the
-            # aggregated model itself stays on device)
-            losses, lrs, rel_dev = jax.device_get(
-                (aux["losses"], aux["lrs"], aux["rel"]))
-            if gated:
-                div_dev, sync_dev = jax.device_get(
-                    (aux["div"], aux["synced"]))
-        else:
-            # staging all T_i epochs at once would cost device memory linear
-            # in T_i (which ILE doubles); chain chunk executables instead.
-            # j0/T_i/ge0/sched/total are traced, so chunks reuse one
-            # compiled program across doublings AND schedule swaps.
-            if not gated:
+                sync_ref = learner._sync_ref(state)
+                delta = engine_mod.stage(learner._round_delta(state),
+                                         np.float32)
+            # the ragged-shard validity mask rides in traced right after the
+            # staged batches (absent entirely on the unmasked executables);
+            # the liveness row (elastic membership) follows the same way
+            mask_args = (learner.batch_mask,) if self._masked else ()
+            live_np = learner._live_np(state)
+            if self._live:
+                live_row = engine_mod.stage(live_np, np.float32)
+                mask_args = mask_args + (live_row,)
+            # state["params"]/["opt"] are reassigned immediately after every
+            # donating call below, so an exception mid-round can never leave
+            # state holding deleted buffers.
+            if single:
+                # stateful codec: the residual rides in right after
+                # opt_state and comes back in the aux dict (device-side,
+                # like new_avg)
+                lead = ((state["params"], state["opt"], state["residual"])
+                        if self._stateful
+                        else (state["params"], state["opt"]))
+                if not gated:
+                    # Eq. 4 compares against the entry params inside the
+                    # executable, so the previous shared model is dead
+                    # here: release it before the dispatch (a whole model
+                    # of device memory at published widths)
+                    state["prev_avg"] = None
+                if gated:
+                    out_p, out_o, aux = self._round(
+                        *lead, batches, *mask_args,
+                        ge0, sched, total, sync_ref, delta, agg_w)
+                else:
+                    out_p, out_o, aux = self._round(
+                        *lead, batches, *mask_args,
+                        ge0, sched, total, agg_w)
+                state["params"], state["opt"] = out_p, out_o
+                if self._stateful:
+                    state["residual"] = aux["residual"]
+                new_avg = aux["new_avg"]
+                # the round's single host sync (scalars/loss curves only —
+                # the aggregated model itself stays on device)
+                wanted = (aux["losses"], aux["lrs"], aux["rel"])
+                if gated:
+                    wanted += (aux["div"], aux["synced"])
+            elif not gated:
                 # the entry shared model sits in the first LIVE slot (slot
                 # 0 on the static path)
                 k0 = 0 if live_np is None else int(np.argmax(live_np))
                 old_avg = averaging.unstack_participant(state["params"], k0)
+        if not single:
+            # staging all T_i epochs at once would cost device memory linear
+            # in T_i (which ILE doubles); chain chunk executables instead.
+            # j0/T_i/ge0/sched/total are traced, so chunks reuse one
+            # compiled program across doublings AND schedule swaps.
             lparts, rparts, j0 = [], [], 0
             while j0 < T_i:
                 C = min(self.chunk, T_i - j0)
-                batches = engine_mod.stack_epoch_batches(
-                    [epoch_batches_fn(i, j) for j in range(j0, j0 + C)])
-                params, opt_st, l, r = self._epochs(
-                    state["params"], state["opt"], batches, *mask_args,
-                    engine_mod.stage(j0, np.int32),
-                    engine_mod.stage(T_i, np.int32), ge0, sched, total)
-                state["params"], state["opt"] = params, opt_st
+                with spans.span("colearn.input"):
+                    batches = engine_mod.stack_epoch_batches(
+                        [epoch_batches_fn(i, j) for j in range(j0, j0 + C)])
+                with spans.span("colearn.dispatch"):
+                    params, opt_st, l, r = self._epochs(
+                        state["params"], state["opt"], batches, *mask_args,
+                        engine_mod.stage(j0, np.int32),
+                        engine_mod.stage(T_i, np.int32), ge0, sched, total)
+                    state["params"], state["opt"] = params, opt_st
                 lparts.append(l)
                 rparts.append(r)
                 j0 += C
-            # stateful codec: the residual enters finalize right after
-            # opt_state (after params on the opt-free static variant) and
-            # a new residual is appended to the outputs
-            res_in = (state["residual"],) if self._stateful else ()
-            if gated:
-                fin_args = ((sync_ref, delta, live_row, agg_w) if self._live
-                            else (sync_ref, delta, agg_w))
-                out = self._finalize(state["params"], state["opt"],
-                                     *res_in, *fin_args)
-                if self._stateful:
-                    (out_p, out_o, rel_t, div_t, sync_t, new_avg,
-                     out_res) = out
-                    state["residual"] = out_res
+            with spans.span("colearn.dispatch"):
+                # stateful codec: the residual enters finalize right after
+                # opt_state (after params on the opt-free static variant)
+                # and a new residual is appended to the outputs
+                res_in = (state["residual"],) if self._stateful else ()
+                if gated:
+                    fin_args = ((sync_ref, delta, live_row, agg_w)
+                                if self._live else (sync_ref, delta, agg_w))
+                    out = self._finalize(state["params"], state["opt"],
+                                         *res_in, *fin_args)
+                    if self._stateful:
+                        (out_p, out_o, rel_t, div_t, sync_t, new_avg,
+                         out_res) = out
+                        state["residual"] = out_res
+                    else:
+                        out_p, out_o, rel_t, div_t, sync_t, new_avg = out
+                    wanted = (lparts, rparts, rel_t, div_t, sync_t)
                 else:
-                    out_p, out_o, rel_t, div_t, sync_t, new_avg = out
+                    if self._live:
+                        # live variant threads opt_state so dead rows keep
+                        # it
+                        out = self._finalize(
+                            state["params"], state["opt"], *res_in, old_avg,
+                            live_row, agg_w)
+                    else:
+                        out = self._finalize(
+                            state["params"], *res_in, old_avg, agg_w)
+                    if self._stateful:
+                        out_p, out_o, rel_t, new_avg, out_res = out
+                        state["residual"] = out_res
+                    else:
+                        out_p, out_o, rel_t, new_avg = out
+                    wanted = (lparts, rparts, rel_t)
                 state["params"], state["opt"] = out_p, out_o
-                lparts, rparts, rel_dev, div_dev, sync_dev = jax.device_get(
-                    (lparts, rparts, rel_t, div_t, sync_t))
-            else:
-                if self._live:
-                    # live variant threads opt_state so dead rows keep it
-                    out = self._finalize(
-                        state["params"], state["opt"], *res_in, old_avg,
-                        live_row, agg_w)
-                else:
-                    out = self._finalize(
-                        state["params"], *res_in, old_avg, agg_w)
-                if self._stateful:
-                    out_p, out_o, rel_t, new_avg, out_res = out
-                    state["residual"] = out_res
-                else:
-                    out_p, out_o, rel_t, new_avg = out
-                state["params"], state["opt"] = out_p, out_o
-                lparts, rparts, rel_dev = jax.device_get(
-                    (lparts, rparts, rel_t))
-            losses = np.concatenate(lparts)
-            lrs = np.concatenate(rparts)
+        with spans.span("colearn.fetch"):
+            got = jax.device_get(wanted)
+        if gated:
+            losses, lrs, rel_dev, div_dev, sync_dev = got
+        else:
+            (losses, lrs, rel_dev), div_dev, sync_dev = got, None, True
+        if not single:
+            losses = np.concatenate(losses)
+            lrs = np.concatenate(lrs)
         synced = bool(sync_dev)
         if not synced:
             rel = float(div_dev)

@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.analysis import spans
 from repro.core import api, averaging, engine as engine_mod
 from repro.core import membership as membership_mod
 from repro.optim.optimizers import get_optimizer
@@ -70,6 +71,8 @@ class RoundLog:
     synced: bool = True
     live: int = -1           # live participants this round (K when static;
                              # -1 only on legacy hand-built logs)
+    host_ms: dict | None = None  # host span name -> ms (``analysis.spans``);
+                                 # None on hand-built logs
 
 
 @dataclass
@@ -361,23 +364,40 @@ class CoLearner:
         round's state transition lands — the publication hook for
         continuous operation (e.g. ``ModelBank.publish_from``). Its
         return value is ignored; the round's state is returned unchanged.
+
+        The round runs inside the host span ``colearn.round``, and its
+        phases inside ``colearn.membership``, ``colearn.input``,
+        ``colearn.dispatch``, ``colearn.fetch``, ``colearn.finish`` and
+        ``colearn.publish`` (``repro.analysis.spans``); the log entry's
+        ``host_ms`` holds each one's milliseconds.
         """
-        if self._churn_active:
-            i = state["round"]
-            new_live = self.churn.live_mask(i, self.cfg.n_participants)
-            if not np.any(new_live):
-                raise ValueError(
-                    f"churn schedule {self.churn.name!r} leaves zero live "
-                    f"participants at round {i}")
-            state["membership"] = state["membership"].step(i, new_live)
-            for k in state["membership"].joined(i):
-                # warm join: restart local training from the last SYNCED
-                # shared model (paper failure semantics, elastic form)
-                self.restart_participant(state, k)
-        state = self._runner.run_round(state, epoch_batches_fn)
-        if on_round_end is not None:
-            on_round_end(self, state)
+        with spans.round_span("colearn.round") as host_ms:
+            if self._churn_active:
+                with spans.span("colearn.membership"):
+                    self._advance_membership(state)
+            state = self._runner.run_round(state, epoch_batches_fn)
+            # the record fills until the round closes: publish and the
+            # round's own time land after the log entry is made
+            state["log"][-1].host_ms = host_ms
+            if on_round_end is not None:
+                with spans.span("colearn.publish"):
+                    on_round_end(self, state)
         return state
+
+    def _advance_membership(self, state):
+        """Step the churn schedule's round mask into the membership and
+        warm-start every slot that joined this round."""
+        i = state["round"]
+        new_live = self.churn.live_mask(i, self.cfg.n_participants)
+        if not np.any(new_live):
+            raise ValueError(
+                f"churn schedule {self.churn.name!r} leaves zero live "
+                f"participants at round {i}")
+        state["membership"] = state["membership"].step(i, new_live)
+        for k in state["membership"].joined(i):
+            # warm join: restart local training from the last SYNCED
+            # shared model (paper failure semantics, elastic form)
+            self.restart_participant(state, k)
 
     def _finish_round(self, state, i, T_i, rel, local_losses, lr_first,
                       lr_last, averaged, fresh_opt, new_avg, synced=True,
@@ -394,42 +414,44 @@ class CoLearner:
         error-feedback codec's post-round memory (None for stateless
         codecs or when the runner already stored it on ``state``).
         """
-        state["params"], state["opt"] = averaged, fresh_opt
-        state["prev_avg"] = new_avg
-        if residual is not None:
-            state["residual"] = residual
-        if self._churn_active:
-            mem = state["membership"]
-            events, n_live = mem.round_events(i), mem.n_live
-        else:
-            events, n_live = (), self.cfg.n_participants
-        state["ctrl"] = self.sync_policy.update(state["ctrl"], i, rel,
-                                                synced, events=events)
-        state["global_epoch"] += T_i
-        # comm volume per participant, priced by the aggregator through the
-        # codec (compressed upload + raw download; gossip pays wire both
-        # ways); round-independent accounting (all built-in aggregators) is
-        # computed once — flat-codec pricing rebuilds a host-side layout
-        # table, which must stay off the per-round path. Under active churn
-        # the live set changes the bill per round, so the cache is bypassed
-        # and only live rows are billed.
-        if not synced:
-            comm = 0
-        elif self._churn_active:
-            comm = self.aggregator.comm_bytes(
-                self.codec, state["params"], i,
-                live=state["membership"].live_mask())
-        elif self.aggregator.static_comm:
-            if self._comm_cache is None:
-                self._comm_cache = self.aggregator.comm_bytes(
-                    self.codec, state["params"], i)
-            comm = self._comm_cache
-        else:
-            comm = self.aggregator.comm_bytes(self.codec, state["params"], i)
-        state["round"] = i + 1
-        state["log"].append(RoundLog(i, T_i, lr_first, lr_last, rel,
-                                     local_losses, comm, synced,
-                                     live=n_live))
+        with spans.span("colearn.finish"):
+            state["params"], state["opt"] = averaged, fresh_opt
+            state["prev_avg"] = new_avg
+            if residual is not None:
+                state["residual"] = residual
+            if self._churn_active:
+                mem = state["membership"]
+                events, n_live = mem.round_events(i), mem.n_live
+            else:
+                events, n_live = (), self.cfg.n_participants
+            state["ctrl"] = self.sync_policy.update(state["ctrl"], i, rel,
+                                                    synced, events=events)
+            state["global_epoch"] += T_i
+            # comm volume per participant, priced by the aggregator through
+            # the codec (compressed upload + raw download; gossip pays wire
+            # both ways); round-independent accounting (all built-in
+            # aggregators) is computed once — flat-codec pricing rebuilds a
+            # host-side layout table, which must stay off the per-round
+            # path. Under active churn the live set changes the bill per
+            # round, so the cache is bypassed and only live rows are billed.
+            if not synced:
+                comm = 0
+            elif self._churn_active:
+                comm = self.aggregator.comm_bytes(
+                    self.codec, state["params"], i,
+                    live=state["membership"].live_mask())
+            elif self.aggregator.static_comm:
+                if self._comm_cache is None:
+                    self._comm_cache = self.aggregator.comm_bytes(
+                        self.codec, state["params"], i)
+                comm = self._comm_cache
+            else:
+                comm = self.aggregator.comm_bytes(self.codec,
+                                                  state["params"], i)
+            state["round"] = i + 1
+            state["log"].append(RoundLog(i, T_i, lr_first, lr_last, rel,
+                                         local_losses, comm, synced,
+                                         live=n_live))
         return state
 
     # legacy handles used by tests/benchmarks to poke at the fused

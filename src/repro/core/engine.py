@@ -96,6 +96,8 @@ contiguous buffer.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -106,6 +108,13 @@ from repro.core.schedule import (divergence_traced, relative_change_traced,
                                  switch_lr)
 from repro.kernels import ops as kops
 from repro.optim.optimizers import apply_updates
+
+#: named scopes of the round's device work, which a profiler trace shows
+#: in each operation's op name: the local epochs' scan (the loop op and
+#: everything in it) and the Eq. 2 aggregate; the Eq. 4 metric and the
+#: gated divergence carry ``schedule.EQ4_REL``
+LOCAL_STEP = "local_step"
+EQ2_WIRE = "eq2_wire"
 
 
 def stage(value, dtype=None):
@@ -272,8 +281,10 @@ def _make_epoch_scan(epoch_fn, lr_fn, masked=False, live=False):
                                             *extra)
             return (params, ostate), (loss, lr)
 
-        return jax.lax.scan(body, (stacked_params, opt_state),
-                            (j0 + jnp.arange(n), batches))
+        # the scope wraps the scan, so the loop op itself carries it
+        with jax.named_scope(LOCAL_STEP):
+            return jax.lax.scan(body, (stacked_params, opt_state),
+                                (j0 + jnp.arange(n), batches))
     return scan_epochs
 
 
@@ -472,19 +483,30 @@ def as_aggregate_fn(aggregate_fn=None, compress_fn=None, average_fn=None):
     ``compress_fn`` upload transform followed by a one-argument
     ``average_fn`` (default ``averaging.average_pjit``); the pair is
     wrapped, ignoring weights. Passing both surfaces is an error.
+
+    Either way the aggregate runs inside the named scope ``eq2_wire``, so
+    every codec and aggregator — the flat wire kernel with its relayouts,
+    the exact mean, gossip, the pod's ``shard_map`` psum — carries it in
+    the compiled program's op names.
     """
     if aggregate_fn is not None:
         if compress_fn is not None or average_fn is not None:
             raise ValueError(
                 "pass aggregate_fn OR compress_fn/average_fn, not both")
-        return aggregate_fn
-    if average_fn is None:
-        average_fn = averaging.average_pjit
+    else:
+        if average_fn is None:
+            average_fn = averaging.average_pjit
 
-    def aggregate(stacked, weights=None):
-        del weights                     # legacy pair: statically uniform
-        uploaded = compress_fn(stacked) if compress_fn is not None else stacked
-        return average_fn(uploaded)
+        def aggregate_fn(stacked, weights=None):
+            del weights                 # legacy pair: statically uniform
+            uploaded = (compress_fn(stacked) if compress_fn is not None
+                        else stacked)
+            return average_fn(uploaded)
+
+    @functools.wraps(aggregate_fn)
+    def aggregate(*args, **kwargs):
+        with jax.named_scope(EQ2_WIRE):
+            return aggregate_fn(*args, **kwargs)
     return aggregate
 
 

@@ -118,6 +118,11 @@ class EpochController:
         return dataclasses.replace(self, T=T, history=self.history + (entry,))
 
 
+#: the named scope of the Eq. 4 metric and the gated divergence in a
+#: compiled round (``repro.core.engine``)
+EQ4_REL = "eq4_rel"
+
+
 def relative_change_traced(new_avg, old_avg):
     """Eq. 4 metric as a traced scalar — usable inside jit/scan.
 
@@ -125,13 +130,14 @@ def relative_change_traced(new_avg, old_avg):
     accumulated on-device in float32. The fused round engine embeds this
     right after Eq. 2 averaging so the whole round has one host sync.
     """
-    num = jnp.zeros((), jnp.float32)
-    den = jnp.zeros((), jnp.float32)
-    for a, b in zip(jax.tree.leaves(new_avg), jax.tree.leaves(old_avg)):
-        d = a.astype(jnp.float32) - b.astype(jnp.float32)
-        num += jnp.sum(d * d)
-        den += jnp.sum(b.astype(jnp.float32) ** 2)
-    return jnp.sqrt(num) / jnp.maximum(jnp.sqrt(den), 1e-12)
+    with jax.named_scope(EQ4_REL):
+        num = jnp.zeros((), jnp.float32)
+        den = jnp.zeros((), jnp.float32)
+        for a, b in zip(jax.tree.leaves(new_avg), jax.tree.leaves(old_avg)):
+            d = a.astype(jnp.float32) - b.astype(jnp.float32)
+            num += jnp.sum(d * d)
+            den += jnp.sum(b.astype(jnp.float32) ** 2)
+        return jnp.sqrt(num) / jnp.maximum(jnp.sqrt(den), 1e-12)
 
 
 @jax.jit
@@ -162,24 +168,25 @@ def divergence_traced(stacked, ref, live=None):
     stale parameters neither inflate nor dilute the drift signal. ``None``
     keeps the exact static-K reduction (bit-compatible).
     """
-    num = jnp.zeros((), jnp.float32)
-    den = jnp.zeros((), jnp.float32)
-    K = jax.tree.leaves(stacked)[0].shape[0]
-    if live is None:
+    with jax.named_scope(EQ4_REL):
+        num = jnp.zeros((), jnp.float32)
+        den = jnp.zeros((), jnp.float32)
+        K = jax.tree.leaves(stacked)[0].shape[0]
+        if live is None:
+            for t, r in zip(jax.tree.leaves(stacked), jax.tree.leaves(ref)):
+                d = t.astype(jnp.float32) - r.astype(jnp.float32)[None]
+                num += jnp.sum(d * d)
+                den += jnp.sum(r.astype(jnp.float32) ** 2)
+            return jnp.sqrt(num / K) / jnp.maximum(jnp.sqrt(den), 1e-12)
+
+        w = live.astype(jnp.float32)
         for t, r in zip(jax.tree.leaves(stacked), jax.tree.leaves(ref)):
             d = t.astype(jnp.float32) - r.astype(jnp.float32)[None]
-            num += jnp.sum(d * d)
+            per_k = jnp.sum(d * d, axis=tuple(range(1, d.ndim)))
+            num += jnp.sum(w * per_k)
             den += jnp.sum(r.astype(jnp.float32) ** 2)
-        return jnp.sqrt(num / K) / jnp.maximum(jnp.sqrt(den), 1e-12)
-
-    w = live.astype(jnp.float32)
-    for t, r in zip(jax.tree.leaves(stacked), jax.tree.leaves(ref)):
-        d = t.astype(jnp.float32) - r.astype(jnp.float32)[None]
-        per_k = jnp.sum(d * d, axis=tuple(range(1, d.ndim)))
-        num += jnp.sum(w * per_k)
-        den += jnp.sum(r.astype(jnp.float32) ** 2)
-    n_live = jnp.maximum(jnp.sum(w), 1.0)
-    return jnp.sqrt(num / n_live) / jnp.maximum(jnp.sqrt(den), 1e-12)
+        n_live = jnp.maximum(jnp.sum(w), 1.0)
+        return jnp.sqrt(num / n_live) / jnp.maximum(jnp.sqrt(den), 1e-12)
 
 
 @jax.jit

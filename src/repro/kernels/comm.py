@@ -90,6 +90,7 @@ def quant_avg_dequant_fwd(buf, *, block=DEFAULT_BLOCK, bits=8,
         out_specs=pl.BlockSpec((ROWS, block), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nb, block), jnp.float32),
         interpret=interpret,
+        name="eq2_flat_mean",
     )(xb)
     return out.reshape(n_pad)[:n]
 
@@ -118,5 +119,6 @@ def quant_avg_dequant_ef_fwd(buf, residual, *, block=DEFAULT_BLOCK, bits=8,
         out_shape=[jax.ShapeDtypeStruct((nb, block), jnp.float32),
                    jax.ShapeDtypeStruct((K, nb, block), jnp.float32)],
         interpret=interpret,
+        name="eq2_flat_mean_ef",
     )(xb, eb)
     return out.reshape(n_pad)[:n], ne.reshape(K, n_pad)[:, :n]

@@ -102,5 +102,6 @@ def flash_attention_fwd(q, k, v, *, n_kv_heads, window=0, softmax_scale=None,
             pltpu.VMEM((bq, hd_v), jnp.float32),   # output accumulator
         ],
         interpret=interpret,
+        name="attention_flash",
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3)

@@ -91,5 +91,6 @@ def mlstm_fwd(q, k, v, ig, fg, *, chunk=DEFAULT_CHUNK, interpret=False):
             pltpu.VMEM((1, 1), jnp.float32),     # stabilizer m
         ],
         interpret=interpret,
+        name="mlstm_chunk_scan",
     )(qt, kt, vt, it, ft)
     return h.transpose(0, 2, 1, 3)

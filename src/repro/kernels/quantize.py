@@ -118,6 +118,7 @@ def quantize_blockwise_fwd(x, *, block=DEFAULT_BLOCK, bits=8,
         out_shape=[jax.ShapeDtypeStruct((nb, block), jnp.int8),
                    jax.ShapeDtypeStruct((nb, 1), jnp.float32)],
         interpret=interpret,
+        name="eq2_quantize_blockwise",
     )(xb)
     return pack_codes(q, bits), s[:, 0], x.shape
 
@@ -148,5 +149,6 @@ def dequantize_blockwise_fwd(q, scale, shape, *, bits=8, interpret=False):
         out_specs=pl.BlockSpec((ROWS, block), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nb_pad, block), jnp.float32),
         interpret=interpret,
+        name="eq2_dequantize_blockwise",
     )(q, scale[:, None])
     return x.reshape(-1)[:n].reshape(shape)

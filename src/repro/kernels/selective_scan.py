@@ -94,5 +94,6 @@ def selective_scan_fwd(xc, dt, Bm, Cm, A, D, *, block_d=DEFAULT_BD,
         ],
         scratch_shapes=[pltpu.VMEM((bd, st), jnp.float32)],
         interpret=interpret,
+        name="ssm_selective_scan",
     )(xc, dt, Bm, Cm, A, d2)
     return y, h

@@ -408,12 +408,16 @@ def main(argv=None, on_round_end=None):
         sync_s = "" if log.synced else " SKIP(sync)"
         if churn is not None:
             sync_s += f" live={log.live}/{K}"
+        # the round's host phases (ms), from its spans
+        host_s = " ".join(f"{name.split('.')[-1]}={ms:.1f}"
+                          for name, ms in (log.host_ms or {}).items()
+                          if name != "colearn.round")
         print(f"round {log.round}: T={log.T} lr {log.lr_first:.4f}->"
               f"{log.lr_last:.4f} rel_dw={log.rel_change:.4f} "
               f"local_loss={np.mean(log.local_losses):.4f} eval={ev:.4f} "
               f"comm={log.comm_bytes/2**20:.1f}MiB next_T={state['ctrl'].T}"
-              f"{sync_s} (round {round_s:.1f}s, eval {eval_s:.1f}s)",
-              flush=True)
+              f"{sync_s} (round {round_s:.1f}s, eval {eval_s:.1f}s; "
+              f"host ms {host_s})", flush=True)
 
     if args.checkpoint:
         save_round_state(args.checkpoint, state)
