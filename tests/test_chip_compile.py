@@ -96,6 +96,25 @@ def test_wire_kernel_compiles_for_v5e(one_chip, param_shapes, kernel, bits,
     assert "tpu_custom_call" in compiled.as_text()
 
 
+#: grid steps the Eq. 2 kernel may take over the smoke cell's flat buffer:
+#: 8-row steps made 246,533, and the fixed cost of a step, not the bytes,
+#: then set the kernel's time
+MAX_WIRE_GRID_STEPS = 8000
+
+
+def test_wire_kernel_grid_is_coarse_at_the_smoke_buffer(param_shapes):
+    """One ``eq2_flat_mean`` kernel walks the smoke cell's whole flat
+    buffer in a few thousand grid steps, read from the traced program."""
+    layout = flatbuf.make_layout(_stacked(param_shapes, K, lambda t: None))
+    buf = jax.ShapeDtypeStruct((K, layout.n_pad), jnp.float32)
+    jaxpr = jax.make_jaxpr(functools.partial(ops.quant_avg_dequant,
+                                             impl="pallas"))(buf)
+    calls = [e.params for e in jaxpr.jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    assert [c["name"] for c in calls] == ["eq2_flat_mean"]
+    assert np.prod(calls[0]["grid_mapping"].grid) <= MAX_WIRE_GRID_STEPS
+
+
 def test_fused_round_fits_one_v5e(one_chip, cfg, param_shapes):
     """The full-width int8 round of the smoke cell on one chip, built as
     ``CoLearner``'s fused engine builds it (params and optimizer state

@@ -159,19 +159,49 @@ def test_dequantize_rejects_inconsistent_payload():
 # ---------------------------------------------------------------------------
 # fused quantize->average->dequantize (Eq. 2 wire pass)
 # ---------------------------------------------------------------------------
+#: buffers that span several of the kernel's grid tiles (``comm.tile_rows``)
+#: with a partial last tile, for the plain and the EF pass alike: nb = 2R + 40
+#: block rows for the plain pass's R at that K, less 300 elements (ragged n)
+FAT = [(2, 1064 * 256 - 300), (3, 712 * 256 - 300), (4, 552 * 256 - 300)]
+#: the zero tail of a FAT buffer, as the flat buffer's pad is: the ragged
+#: last block row and two whole ones, which must come back exactly zero
+ZERO_TAIL = 2 * 256 + 212
+
+
+def _wire_buf(key, K, n, scale):
+    """(K, n) random rows; a FAT case ends in ``ZERO_TAIL`` zeros."""
+    buf = jax.random.normal(key, (K, n)) * scale
+    if (K, n) not in FAT:
+        return buf
+    from repro.kernels.comm import tile_rows
+    nb = -(-n // 256)
+    for operands in (1, 3):                   # plain pass, EF pass
+        r = tile_rows(K, 256, nb, operands)
+        assert nb > 2 * r and nb % r, (K, n, r)
+    return buf.at[:, -ZERO_TAIL:].set(0.0)
+
+
+def _assert_zero_tail(K, n, *outs):
+    if (K, n) in FAT:
+        for o in outs:
+            np.testing.assert_array_equal(np.asarray(o)[..., -ZERO_TAIL:], 0)
+
+
 @pytest.mark.parametrize("K,n", [
     (1, 8 * 256),        # single participant, exactly one (ROWS, block) tile
     (3, 16 * 256),       # odd K, multiple tiles
     (5, 8 * 256 + 300),  # ragged n: kernel pads to whole tiles internally
+    *FAT,                # several grid tiles, the last partial
 ])
 def test_quant_avg_dequant_matches_ref(K, n):
-    buf = jax.random.normal(KEY, (K, n)) * 3
+    buf = _wire_buf(KEY, K, n, 3)
     m_ref = ref.quant_avg_dequant_ref(buf)
     m_pal = ops.quant_avg_dequant(buf, impl="interpret")
     assert m_pal.shape == (n,)
     # one f32 ULP of slack: the cross-K accumulation order may differ
     np.testing.assert_allclose(np.asarray(m_pal), np.asarray(m_ref),
                                rtol=1e-7, atol=1e-6)
+    _assert_zero_tail(K, n, m_pal)
 
 
 def test_quant_avg_dequant_is_quantized_mean():
@@ -254,24 +284,28 @@ def test_pack_unpack_codes_roundtrip(bits):
 
 
 @pytest.mark.parametrize("bits", [8, 4, 1])
-@pytest.mark.parametrize("K,n", [(3, 16 * 256), (5, 8 * 256 + 300)])
+@pytest.mark.parametrize("K,n", [(3, 16 * 256), (5, 8 * 256 + 300), *FAT])
 def test_quant_avg_dequant_bits_matches_ref(bits, K, n):
-    buf = jax.random.normal(KEY, (K, n)) * 3
+    buf = _wire_buf(KEY, K, n, 3)
     m_ref = ref.quant_avg_dequant_ref(buf, bits=bits)
     m_pal = ops.quant_avg_dequant(buf, bits=bits, impl="interpret")
     assert m_pal.shape == (n,)
     np.testing.assert_allclose(np.asarray(m_pal), np.asarray(m_ref),
                                rtol=2e-6, atol=2e-6)
+    _assert_zero_tail(K, n, m_pal)
 
 
-@pytest.mark.parametrize("bits", [8, 4, 1])
-def test_quant_avg_dequant_ef_oracle_and_kernel(bits):
+@pytest.mark.parametrize("bits,K,n", [
+    *[pytest.param(b, 3, 8 * 256 + 300, id=str(b)) for b in (8, 4, 1)],
+    *[pytest.param(b, K, n, id=f"{b}-fat-K{K}") for K, n in FAT
+      for b in (8, 4, 1)],
+])
+def test_quant_avg_dequant_ef_oracle_and_kernel(bits, K, n):
     """EF fused pass: mean == plain pass on (buf + residual); new residual
     is exactly (buf + residual) - per-row dequant. Kernel == oracle."""
-    K, n = 3, 8 * 256 + 300
     k1, k2 = jax.random.split(KEY)
-    buf = jax.random.normal(k1, (K, n)) * 2
-    res = jax.random.normal(k2, (K, n)) * 0.1
+    buf = _wire_buf(k1, K, n, 2)
+    res = _wire_buf(k2, K, n, 0.1)
     m_ref, e_ref = ref.quant_avg_dequant_ef_ref(buf, res, bits=bits)
     m_pal, e_pal = ops.quant_avg_dequant_ef(buf, res, bits=bits,
                                             impl="interpret")
@@ -279,6 +313,7 @@ def test_quant_avg_dequant_ef_oracle_and_kernel(bits):
                                rtol=2e-6, atol=2e-6)
     np.testing.assert_allclose(np.asarray(e_pal), np.asarray(e_ref),
                                rtol=2e-6, atol=2e-6)
+    _assert_zero_tail(K, n, m_pal, e_pal)
     # the mean is the plain fused pass over the compensated buffer
     m_plain = ref.quant_avg_dequant_ref(buf + res, bits=bits)
     np.testing.assert_allclose(np.asarray(m_ref), np.asarray(m_plain),
