@@ -34,7 +34,7 @@ import time
 
 import numpy as np
 
-from bench import gen, model
+from bench import gen, model, spec
 from bench.spec import ROOT
 
 sys.path.insert(0, os.path.join(ROOT, "src"))
@@ -43,27 +43,6 @@ sys.path.insert(0, os.path.join(ROOT, "src"))
 READ_AFTER = (1, 3)
 #: rounds run in set-up: the reference follows them all
 SETUP_ROUNDS = max(READ_AFTER)
-
-
-def program_config(cfg):
-    """The program's ``ModelConfig`` for a benchmark configuration file.
-
-    Fails where the file states what the program's decoder cannot run."""
-    from repro.configs.base import ModelConfig
-    if cfg.get("hidden_act", "silu") != "silu":
-        raise ValueError(f"hidden_act {cfg['hidden_act']!r}: the program's "
-                         "feed-forward is SwiGLU")
-    if float(cfg.get("partial_rotary_factor", 1.0)) != 1.0:
-        raise ValueError("the program's decoder rotates whole heads only")
-    for k in ("bias", "attention_bias", "mlp_bias", "lm_head_bias"):
-        if cfg.get(k):
-            raise ValueError(f"{k}: the program's decoder has no biases")
-    m = model.dims(cfg)
-    return ModelConfig(
-        name=cfg["name"], family="dense", n_layers=m["L"], d_model=m["d"],
-        n_heads=m["H"], n_kv_heads=m["KV"], d_ff=m["ff"],
-        vocab_size=m["V"], head_dim=m["hd"], norm_eps=m["eps"],
-        rope_theta=m["theta"], tie_embeddings=m["tie"])
 
 
 def corpus(cfg, traffic, seed):
@@ -136,7 +115,7 @@ class Cell:
 
     def __init__(self, cfg, traffic, seed, devices):
         self.cfg, self.traffic, self.seed = cfg, traffic, seed
-        self.pcfg = program_config(cfg)
+        self.pcfg = spec.arch(cfg).program_config(cfg)
         x, y = corpus(cfg, traffic, seed)
         self.data = make_data(x, y, traffic, seed)
         #: the benchmark's own batches, which the reference trains on
@@ -157,8 +136,8 @@ class Cell:
         want = jax.tree.structure(jax.eval_shape(
             lambda k: tr.init_params(k, self.pcfg),
             jax.ShapeDtypeStruct((2,), np.uint32)))
-        spec = model.param_shapes(self.cfg)
-        got = jax.tree.structure(spec, is_leaf=model.is_spec)
+        got = jax.tree.structure(model.param_shapes(self.cfg),
+                                 is_leaf=model.is_spec)
         if got != want:
             raise ValueError(f"benchmark parameter tree {got} is not the "
                              f"program's {want}")
@@ -247,9 +226,8 @@ class PodCell(Cell):
         self.mesh = auto_mesh((self.K,), ("pod",), devices)
         self.aggregator, self.schedule, self.sync = aggregator, schedule, sync
         self.ccfg = ccfg
-        spec = model.param_shapes(cfg)
         specs = jax.tree.map(lambda s: P("pod", *([None] * len(s[0]))),
-                             spec, is_leaf=model.is_spec)
+                             model.param_shapes(cfg), is_leaf=model.is_spec)
         self.shard = jax.tree.map(lambda s: NamedSharding(self.mesh, s),
                                   specs)
         self.batch_sharding = NamedSharding(self.mesh, P(None, "pod"))

@@ -2,13 +2,10 @@
 the Eq. 2 pass, and the table of peaks (``bench/peaks.json``).
 
 Model FLOPs count the matrix products of the forward and backward passes,
-6 a parameter a token (2 forward, 4 backward), over every matmul weight:
-the attention projections, the feed-forward and the output head (the tied
-embedding counts once, as the head; its row gather counts nothing). The
-causal attention itself adds, a layer and a token, 2 products (scores and
-values) of 2 FLOPs over H x head_dim x the mean visible context
-(S + 1) / 2, times 3 for the backward pass. Recomputation under remat is
-not counted.
+6 a parameter a token (2 forward, 4 backward), over the weights a token
+passes through, plus the attention itself; the configuration's
+architecture module (``bench/arch/<model_type>.py``) counts them.
+Recomputation under remat is not counted.
 
 The Eq. 2 pass reads K f32 rows of the flat wire buffer and writes one
 averaged row, whatever implements it. The buffer holds every leaf from a
@@ -23,8 +20,8 @@ import os
 
 import jax
 
-from bench.model import (WIRE_BLOCK, WIRE_ROWS, dims, is_spec,
-                         param_shapes)
+from bench import spec
+from bench.model import WIRE_BLOCK, WIRE_ROWS, is_spec, param_shapes
 
 PEAKS_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                           "peaks.json")
@@ -47,20 +44,16 @@ def peaks(device_kind):
 
 
 def matmul_params(cfg):
-    """Weights that enter a matrix product once a token."""
-    m = dims(cfg)
-    d, H, KV, hd, ff, V, L = (m[k] for k in ("d", "H", "KV", "hd", "ff",
-                                             "V", "L"))
-    attn = d * H * hd + 2 * d * KV * hd + H * hd * d
-    ffn = 3 * d * ff
-    return L * (attn + ffn) + d * V
+    """Weights that enter a matrix product once a token, for a module
+    that counts them apart (the dense decoder's helper, which
+    ``bench/tests/test_flops.py`` checks by hand). The harness reads
+    ``train_flops_per_token`` only."""
+    return spec.arch(cfg).matmul_params(cfg)
 
 
 def train_flops_per_token(cfg, seq_len):
     """Model FLOPs of one training token (forward and backward)."""
-    m = dims(cfg)
-    attn = 3 * 2 * 2 * m["H"] * m["hd"] * (seq_len + 1) / 2 * m["L"]
-    return 6 * matmul_params(cfg) + attn
+    return spec.arch(cfg).train_flops_per_token(cfg, seq_len)
 
 
 def param_count(cfg):

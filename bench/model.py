@@ -1,17 +1,15 @@
 """The benchmark's weights and its plain reference of the co-learning round.
 
-Nothing here imports the program. ``init_params`` makes a configuration's
-weights from a seed on the device in one jitted call, in the parameter
-tree the program's decoder takes (``embed``, ``final_norm``, ``head``
-unless the embeddings are tied, one segment ``p0`` whose leaves stack the
-layers). The reference is written from the configuration alone: a
-pre-norm decoder with RMSNorm, rotary embeddings over whole heads
-(``rotate_half`` layout), causal grouped-query attention and a SwiGLU
-feed-forward; the mean next-token cross-entropy; SGD under the round's
-rate (Eq. 3); and the Eq. 2 average of the participants, through the flat
-wire's blockwise quantization where the traffic sets it. The reference
-computes in float32 with every matrix product at ``HIGHEST`` precision
-(``dtype=bfloat16`` gives the lower-precision control).
+Nothing here imports the program, and nothing here knows an
+architecture: the configuration's module (``bench/arch/<model_type>.py``,
+found by ``bench.spec.arch``) gives the parameter tree and the loss of
+one batch. ``init_params`` makes a configuration's weights from a seed on
+the device in one jitted call, from that tree. The reference runs the
+module's loss under SGD at the round's rate (Eq. 3), and the Eq. 2
+average of the participants, through the flat wire's blockwise
+quantization where the traffic sets it. It computes in float32 with every
+matrix product at ``HIGHEST`` precision (``dtype=bfloat16`` gives the
+lower-precision control).
 """
 from __future__ import annotations
 
@@ -22,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import spec
 from bench.gen import seed_key
 
 #: the flat wire's quantization block and tile rows (one scale a block of
@@ -31,43 +30,10 @@ WIRE_ROWS = 8
 QMAX = {8: 127.0, 4: 7.0}
 
 
-def dims(cfg):
-    """The sizes the reference and the FLOP count read from a config."""
-    d = cfg["hidden_size"]
-    H = cfg["num_attention_heads"]
-    return {
-        "d": d, "H": H, "KV": cfg["num_key_value_heads"],
-        "hd": cfg.get("head_dim") or d // H,
-        "ff": cfg["intermediate_size"], "V": cfg["vocab_size"],
-        "L": cfg["num_hidden_layers"],
-        "tie": bool(cfg.get("tie_word_embeddings", False)),
-        "eps": float(cfg["rms_norm_eps"]),
-        "theta": float(cfg["rope_theta"]),
-    }
-
-
 def param_shapes(cfg):
-    """The parameter tree as ``(shape, fan_in or None)`` leaves; ``None``
-    marks a norm gain (initialised to ones)."""
-    m = dims(cfg)
-    d, H, KV, hd, ff, V, L = (m[k] for k in ("d", "H", "KV", "hd", "ff",
-                                             "V", "L"))
-    tree = {
-        "embed": {"table": ((V, d), d)},
-        "final_norm": {"g": ((d,), None)},
-        "segments": [{"p0": {
-            "norm1": {"g": ((L, d), None)},
-            "mixer": {"wq": ((L, d, H, hd), d), "wk": ((L, d, KV, hd), d),
-                      "wv": ((L, d, KV, hd), d),
-                      "wo": ((L, H, hd, d), H * hd)},
-            "norm2": {"g": ((L, d), None)},
-            "ffn": {"wi": ((L, d, ff), d), "wg": ((L, d, ff), d),
-                    "wo": ((L, ff, d), ff)},
-        }}],
-    }
-    if not m["tie"]:
-        tree["head"] = {"w": ((d, V), d)}
-    return tree
+    """The configuration's parameter tree as ``(shape, init)`` leaves, as
+    its architecture module gives it (``bench.spec.arch``)."""
+    return spec.arch(cfg).param_shapes(cfg)
 
 
 def is_spec(x):
@@ -75,16 +41,20 @@ def is_spec(x):
 
 
 def _init(key, cfg, dtype):
-    spec = param_shapes(cfg)
-    leaves, treedef = jax.tree.flatten(spec, is_leaf=is_spec)
+    leaves, treedef = jax.tree.flatten(param_shapes(cfg), is_leaf=is_spec)
     keys = jax.random.split(key, len(leaves))
     out = []
-    for k, (shape, fan_in) in zip(keys, leaves):
-        if fan_in is None:
+    for k, (shape, init) in zip(keys, leaves):
+        if init == "ones":
             out.append(jnp.ones(shape, dtype))
-        else:
+        elif init == "zeros":
+            out.append(jnp.zeros(shape, dtype))
+        elif isinstance(init, int) and init > 0:
             w = jax.random.truncated_normal(k, -2.0, 2.0, shape, jnp.float32)
-            out.append((w * fan_in ** -0.5).astype(dtype))
+            out.append((w * init ** -0.5).astype(dtype))
+        else:
+            raise ValueError(f"leaf {shape}: init {init!r} is no fan-in, "
+                             "'ones' or 'zeros'")
     return jax.tree.unflatten(treedef, out)
 
 
@@ -121,68 +91,14 @@ def init_params(cfg, seed, dtype=jnp.float32, stack=0, out_shardings=None):
 
 
 # ---------------------------------------------------------------------------
-# forward, loss and one SGD step
+# one SGD step
 # ---------------------------------------------------------------------------
-def _rmsnorm(x, g, eps):
-    var = jnp.mean(x * x, axis=-1, keepdims=True)
-    return x * jax.lax.rsqrt(var + eps) * g
-
-
-def _rope(x, theta):
-    """Rotary embedding over the whole head, halves rotated together."""
-    S, hd = x.shape[1], x.shape[-1]
-    half = hd // 2
-    inv = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
-    ang = jnp.arange(S, dtype=jnp.float32)[:, None] * inv[None, :]
-    cos = jnp.cos(ang)[None, :, None, :].astype(x.dtype)
-    sin = jnp.sin(ang)[None, :, None, :].astype(x.dtype)
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
-
-
-def loss(params, tokens, labels, cfg, prec):
-    """Mean next-token cross-entropy of one batch; computes in the dtype
-    of ``params``."""
-    m = dims(cfg)
-    ein = functools.partial(jnp.einsum, precision=prec)
-    dt = params["embed"]["table"].dtype
-    x = params["embed"]["table"][tokens]                     # (B, S, d)
-    S = tokens.shape[1]
-    causal = jnp.tril(jnp.ones((S, S), bool))
-    G = m["H"] // m["KV"]
-    lay = params["segments"][0]["p0"]
-    for i in range(m["L"]):
-        p = jax.tree.map(lambda t, i=i: t[i], lay)
-        h = _rmsnorm(x, p["norm1"]["g"], m["eps"])
-        q = _rope(ein("bsd,dhk->bshk", h, p["mixer"]["wq"]), m["theta"])
-        k = _rope(ein("bsd,dhk->bshk", h, p["mixer"]["wk"]), m["theta"])
-        v = ein("bsd,dhk->bshk", h, p["mixer"]["wv"])
-        k = jnp.repeat(k, G, axis=2)
-        v = jnp.repeat(v, G, axis=2)
-        s = ein("bqhk,bshk->bhqs", q, k) * jnp.asarray(m["hd"] ** -0.5, dt)
-        s = jnp.where(causal[None, None], s, jnp.asarray(-jnp.inf, dt))
-        w = jax.nn.softmax(s, axis=-1)
-        o = ein("bhqs,bshk->bqhk", w, v)
-        x = x + ein("bqhk,hkd->bqd", o, p["mixer"]["wo"])
-        h = _rmsnorm(x, p["norm2"]["g"], m["eps"])
-        up = ein("bsd,df->bsf", h, p["ffn"]["wi"])
-        gate = ein("bsd,df->bsf", h, p["ffn"]["wg"])
-        x = x + ein("bsf,fd->bsd", jax.nn.silu(gate) * up, p["ffn"]["wo"])
-    h = _rmsnorm(x, params["final_norm"]["g"], m["eps"])
-    if m["tie"]:
-        logits = ein("bsd,vd->bsv", h, params["embed"]["table"])
-    else:
-        logits = ein("bsd,dv->bsv", h, params["head"]["w"])
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    picked = jnp.take_along_axis(logits, labels[..., None], -1)[..., 0]
-    return jnp.mean(lse - picked)
-
-
 @functools.lru_cache(maxsize=None)
 def _step_fn(cfg_json, prec_name):
     import json
     cfg = json.loads(cfg_json)
     prec = getattr(jax.lax.Precision, prec_name)
+    loss = spec.arch(cfg).loss
 
     def step(params, tokens, labels, lr):
         val, grads = jax.value_and_grad(loss)(params, tokens, labels, cfg,

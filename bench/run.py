@@ -9,8 +9,10 @@ the weights and the inputs from the seed and runs the first rounds, the
 first of which compiles; the window then runs rounds for ``--seconds``.
 With ``--trace 0`` the result holds the cell's end-to-end metrics; with
 ``--trace 1`` the window runs under the profiler and the result holds the
-per-layer metrics, each read by ``bench/metrics/<metric>.py``, with the
-device's busy time and a breakdown.
+per-layer metrics, each read by ``bench/metrics/<metric>.py`` from the
+run's ``ctx`` (the reduced trace under ``"trace"``, the trace file itself
+under ``"trace_path"`` until the readers are done), with the device's
+busy time and a breakdown.
 
 Set-up ends in a full garbage collection, after which every object it
 made is frozen out of later collections: the window's collections then
@@ -176,21 +178,21 @@ def run(workload, cfg, traffic, limits, seed, seconds, trace,
     ctx = {"times": times, "tokens": tokens, "setup_s": setup_s,
            "peak_bytes": peak, "compiles": compiles, "cfg": cfg,
            "traffic": traffic, "chips": chips, "peaks": peaks,
-           "trace": None}
+           "trace": None, "trace_path": None}
     device = {"platform": platform, "kind": kind, "count": len(devices),
               "memory_peak_bytes": int(peak)}
     result = {"correct": ok, "attempted": len(times), "failed": failed}
     if trace:
         from bench import trace as trace_mod
         import glob
-        path = glob.glob(os.path.join(TRACE_DIR, "plugins", "profile", "*",
-                                      "*.xplane.pb"))[0]
-        ctx["trace"] = trace_mod.load(path)
-        shutil.rmtree(TRACE_DIR, ignore_errors=True)
+        ctx["trace_path"] = glob.glob(os.path.join(
+            TRACE_DIR, "plugins", "profile", "*", "*.xplane.pb"))[0]
+        ctx["trace"] = trace_mod.load(ctx["trace_path"])
         lo, hi = trace_mod.window(ctx["trace"])
         device["busy_s"] = trace_mod.busy_seconds(ctx["trace"])
         device["window_s"] = hi - lo
         result["metrics"] = trace_metrics(workload, ctx)
+        shutil.rmtree(TRACE_DIR, ignore_errors=True)
         result["device"] = device
         result["breakdown"] = trace_mod.breakdown(ctx["trace"])
     else:

@@ -20,9 +20,15 @@ of that module sees what it always saw, and adds two things:
   ``jax.profiler.ProfileData`` does not give. It comes from xprof's
   ``trace_viewer`` conversion of a copy of the file, made in a temporary
   directory (the conversion writes cache files beside what it reads).
-  Both sources carry each operation's HLO text; the n-th operation with a
-  given text on a device takes the n-th op name that the conversion gives
-  that text there.
+  Both sources carry each operation's HLO text, which names the
+  instruction, and an operation takes the op name that the conversion
+  gives its text on that device (``op_names``). The conversion keeps at
+  most a million events, the latest, so a long window on four chips loses
+  its first rounds there; every round runs the same programs, so the
+  texts of the rounds kept name those lost. Where one text carries
+  several names (two executables that share an instruction's text), its
+  operations are matched from the latest back, and those the conversion
+  dropped stay unnamed.
 
 Clocks: a ``colearn.fetch`` span cannot end before the device has finished
 the program it waits for. ``clock_skew`` measures, for each fetch, its end
@@ -119,6 +125,27 @@ def viewer_ops(path):
     return out
 
 
+def op_names(evs, names):
+    """The op name of each of one device's operations ``evs`` (in time
+    order), from ``names``: HLO text -> the conversion's op names of that
+    text, in time order. A text with one name names all its operations; a
+    text with several pairs its latest operations with its names, in
+    order, and leaves the earlier ones, which the conversion dropped,
+    unnamed ("")."""
+    count = collections.Counter(e.text for e in evs)
+    seen = collections.Counter()
+    out = []
+    for e in evs:
+        v = names.get(e.text, [])
+        i = seen[e.text] - (count[e.text] - len(v))
+        seen[e.text] += 1
+        if len(set(v)) == 1:
+            out.append(v[0])
+        else:
+            out.append(v[i] if 0 <= i < len(v) else "")
+    return out
+
+
 def load(path):
     """The trace at ``path`` with the program's spans and op names, the
     spans aligned to the device's clock (``align``)."""
@@ -126,10 +153,7 @@ def load(path):
     names = viewer_ops(path)
     tf_ops = {}
     for dev, evs in base.devices.items():
-        queues = {text: collections.deque(v)
-                  for text, v in names.get(dev, {}).items()}
-        tf_ops[dev] = [queues[e.text].popleft() if queues.get(e.text)
-                       else "" for e in evs]
+        tf_ops[dev] = op_names(evs, names.get(dev, {}))
     return align(Scoped(base, _host_spans(path, PROGRAM), tf_ops))
 
 

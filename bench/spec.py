@@ -1,13 +1,15 @@
 """Find a cell's files by name: ``BENCHMARK.json`` at the checkout root,
 ``bench/configs/<config>.json``, ``bench/traffic/<traffic>.json``,
-``bench/limits/<workload>.json`` and ``bench/metrics/<metric>.py``.
+``bench/limits/<workload>.json``, ``bench/metrics/<metric>.py`` and, by
+the configuration's ``model_type``, ``bench/arch/<model_type>.py``.
 
-A cell, a configuration, a traffic mix or a per-layer metric is added by
-adding its file and its entry in ``BENCHMARK.json``; nothing here names
-one of them.
+A cell, a configuration, a traffic mix, a per-layer metric or an
+architecture is added by adding its file (and, but for an architecture,
+its entry in ``BENCHMARK.json``); nothing here names one of them.
 """
 from __future__ import annotations
 
+import importlib
 import importlib.util
 import json
 import os
@@ -138,3 +140,24 @@ def metric_reader(name):
     mod = importlib.util.module_from_spec(mod_spec)
     mod_spec.loader.exec_module(mod)
     return mod.read
+
+
+def arch(cfg):
+    """The module ``bench/arch/<cfg["model_type"]>.py`` of a configuration.
+
+    It gives ``program_config(cfg)``, the program's model configuration,
+    raising on what the program cannot run; ``param_shapes(cfg)``, the
+    parameter tree as ``(shape, init)`` leaves, ``init`` a fan-in (a
+    truncated normal over its square root), ``"ones"`` or ``"zeros"``;
+    ``loss(params, tokens, labels, cfg, prec)``, the plain reference's
+    mean next-token cross-entropy of one batch, with any auxiliary term
+    the program's loss carries for the architecture; and
+    ``train_flops_per_token(cfg, seq_len)``, the model FLOPs of one
+    training token over the weights a token passes through."""
+    kind = cfg.get("model_type")
+    if not isinstance(kind, str) or not kind.isidentifier():
+        raise SpecError(f"configuration {cfg.get('name')!r} names no "
+                        f"model_type a module can have (got {kind!r})")
+    if not os.path.isfile(os.path.join(BENCH_DIR, "arch", kind + ".py")):
+        raise SpecError(f"no architecture module bench/arch/{kind}.py")
+    return importlib.import_module("bench.arch." + kind)

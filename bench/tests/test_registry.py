@@ -6,10 +6,11 @@ import os
 import shutil
 import uuid
 
+import jax
 import numpy as np
 import pytest
 
-from bench import drive, gen, spec
+from bench import drive, gen, model, spec
 from bench.tests import tiny
 
 
@@ -67,6 +68,24 @@ def test_every_cell_finds_its_files():
             spec.metric_reader(m["name"])
     for m in bm["per_layer"]:
         spec.metric_reader(m["name"])
+    for c in bm["configs"]:
+        assert c["file"] == f"bench/configs/{c['name']}.json"
+    # every configuration resolves its architecture module, whose tree is
+    # the one the program's decoder initialises, leaf for leaf
+    from repro.models import transformer as tr
+    configs = sorted(f[:-5] for f in os.listdir(
+        os.path.join(spec.BENCH_DIR, "configs")) if f.endswith(".json"))
+    assert {c["name"] for c in bm["configs"]} <= set(configs)
+    for name in configs:
+        cfg = spec.config(name)
+        pcfg = spec.arch(cfg).program_config(cfg)
+        want = jax.eval_shape(lambda k: tr.init_params(k, pcfg),
+                              jax.ShapeDtypeStruct((2,), np.uint32))
+        got = model.param_shapes(cfg)
+        assert jax.tree.structure(got, is_leaf=model.is_spec) == \
+            jax.tree.structure(want), name
+        assert [s for s, _ in jax.tree.leaves(got, is_leaf=model.is_spec)] \
+            == [w.shape for w in jax.tree.leaves(want)], name
 
 
 def test_unknown_traffic_option_is_refused():

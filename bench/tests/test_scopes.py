@@ -163,6 +163,46 @@ def test_conversion_keeps_every_operation(recorded, recorded_path):
     assert loops and not any(loops)
 
 
+def test_ops_the_conversion_dropped_are_named(recorded, recorded_path,
+                                              monkeypatch):
+    """The conversion keeps only its latest million events; the operations
+    of the rounds it dropped take the names their texts have in the
+    rounds it kept."""
+    full = scopes.viewer_ops(recorded_path)
+    # each text names one instruction, and carries one op name
+    assert all(len(set(v)) == 1 for per in full.values()
+               for v in per.values())
+    late = {d: {t: v[len(v) // 2:] for t, v in per.items()}
+            for d, per in full.items()}
+    assert late != full
+    monkeypatch.setattr(scopes, "viewer_ops", lambda path: late)
+    cut = scopes.load(recorded_path)
+    assert cut.tf_ops == recorded.tf_ops
+    for scope in scopes.SCOPES:
+        assert scopes.scope_ms(cut, scope) == scopes.scope_ms(recorded,
+                                                               scope)
+
+
+def test_a_text_with_several_names_is_matched_in_order():
+    """Two executables whose instructions share a text: the conversion's
+    names go to the operations in order, from the latest back, and the
+    operations it dropped stay unnamed; a text with one name names every
+    operation it has."""
+    def ev(text, t):
+        return trace.Event("fusion.1", t, t + 1, text)
+    evs = [ev("%fusion.1 = f32[8] add()", float(t)) for t in range(4)]
+    evs.insert(2, ev("%copy.2 = f32[8] copy()", 9.0))
+    both = ["jit(a)/local_step/add", "jit(b)/eq2_wire/add"] * 2
+    names = {"%fusion.1 = f32[8] add()": both,
+             "%copy.2 = f32[8] copy()": ["jit(a)/copy"]}
+    assert scopes.op_names(evs, names) == [
+        both[0], both[1], "jit(a)/copy", both[2], both[3]]
+    names["%fusion.1 = f32[8] add()"] = both[1:]
+    assert scopes.op_names(evs, names) == [
+        "", both[1], "jit(a)/copy", both[2], both[3]]
+    assert scopes.op_names(evs, {}) == [""] * 5
+
+
 def test_each_round_opens_its_phases_in_order(recorded):
     rounds = [s for s in recorded.program if s.name == "colearn.round"]
     benches = [s for s in recorded.trace.spans if s.name == "bench.round"]
@@ -201,6 +241,39 @@ def test_scope_readers_match_a_hand_sum(recorded_path, name, scope):
     assert want > 0 and got == pytest.approx(want, rel=1e-12)
     assert scopes.scope_seconds(ctx["scoped"], scope, 0) == pytest.approx(
         want * 6e-3, rel=1e-12)
+
+
+def _run_ctx(path, recorded):
+    """What a traced run of the int8 cell hands its readers, for the
+    recorded round."""
+    from bench import flops
+    rounds = [s for s in recorded.trace.spans if s.name == "bench.round"]
+    t = tiny.traffic()
+    per_round = (t["participants"] * t["steps_per_epoch"]
+                 * t["batch_size"] * t["seq_len"])
+    return {"trace": trace.load(path), "trace_path": path,
+            "times": [(s.start, s.end) for s in rounds],
+            "tokens": [per_round] * len(rounds), "setup_s": 1.0,
+            "peak_bytes": 0, "compiles": 0, "cfg": tiny.config(),
+            "traffic": t, "chips": 1, "peaks": flops.peaks("TPU v5 lite")}
+
+
+def test_a_traced_run_hands_its_readers_the_trace(recorded_path, recorded):
+    from bench import run
+    cell = spec.workload("internlm2-k2-int8")
+    got = run.trace_metrics(cell, _run_ctx(recorded_path, recorded))
+    for name, scope in (("local_step_ms", "local_step"),
+                        ("eq2_wire_ms", "eq2_wire"),
+                        ("eq4_rel_ms", "eq4_rel")):
+        assert got[name] == {"value": pytest.approx(
+            scopes.scope_ms(recorded, scope), rel=1e-12), "unit": "ms"}
+    # without the file the scope readers read nothing, and the line
+    # leaves them out
+    bare = run.trace_metrics(cell, dict(_run_ctx(recorded_path, recorded),
+                                        trace_path=None))
+    assert not {"local_step_ms", "eq2_wire_ms", "eq4_rel_ms"} & set(bare)
+    assert set(got) - set(bare) == {"local_step_ms", "eq2_wire_ms",
+                                    "eq4_rel_ms"}
 
 
 def test_scopes_cover_most_of_the_busy_time(recorded):

@@ -84,6 +84,26 @@ def test_collectives_exposed_by_hand():
                                                pytest.approx(0.4))
 
 
+def test_collectives_by_opcode():
+    """A ``shard_map`` psum keeps the name ``psum`` and is an all-reduce by
+    its opcode; an operation that only reads an all-reduce's result is
+    none."""
+    psum = trace.Event("psum.77", 0.0, 0.5,
+                       "%psum.77 = f32[1,8]{1,0} all-reduce(f32[1,8]{1,0} "
+                       "%get-tuple-element.3), channel_id=1")
+    fusion = trace.Event("fusion.9", 0.5, 0.7,
+                         "%fusion.9 = f32[8]{0} fusion(f32[8]{0} "
+                         "%all-reduce.11), kind=kLoop")
+    start = trace.Event("ar.2", 0.7, 0.8, "%ar.2 = (f32[8]{0}, f32[8]{0}) "
+                        "all-reduce-start(f32[8]{0} %p), channel_id=2")
+    assert trace.is_collective(psum) and trace.is_collective(start)
+    assert not trace.is_collective(fusion)
+    tr = trace.Trace(devices={0: [psum, fusion, start]}, asyncs={},
+                     modules={}, spans=[trace.Event("bench.round", 0.0, 1.0)])
+    assert trace.collective_seconds(tr, 0) == (pytest.approx(0.6),
+                                               pytest.approx(0.6))
+
+
 def test_interval_arithmetic():
     assert trace.union([(2, 3), (0, 1), (0.5, 2.5)]) == [(0, 3)]
     assert trace.subtract([(0, 10)], [(1, 2), (5, 6)]) == [(0, 1), (2, 5),

@@ -8,8 +8,8 @@ from __future__ import annotations
 import copy
 
 CONFIG = {
-    "name": "tiny", "hidden_act": "silu", "hidden_size": 256,
-    "intermediate_size": 512, "num_attention_heads": 4,
+    "name": "tiny", "model_type": "internlm2", "hidden_act": "silu",
+    "hidden_size": 256, "intermediate_size": 512, "num_attention_heads": 4,
     "num_key_value_heads": 2, "num_hidden_layers": 2, "vocab_size": 512,
     "rms_norm_eps": 1e-05, "rope_theta": 10000.0,
     "tie_word_embeddings": False,

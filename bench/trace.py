@@ -19,9 +19,14 @@ import re
 
 #: an XLA loop: the local steps' scans lower to these
 LOOP = re.compile(r"while(\.\d+)?$")
-#: operations that move data between chips
+#: operations that move data between chips, by their short name
 COLLECTIVE = re.compile(r"all-reduce|collective-permute|all-gather|"
                         r"reduce-scatter|all-to-all")
+#: the same by the opcode in the HLO text (``%psum.7 = f32[..] all-reduce(``):
+#: a ``shard_map`` psum keeps the name ``psum``
+COLLECTIVE_OPCODE = re.compile(r"\s(?:all-reduce|collective-permute|"
+                               r"all-gather|reduce-scatter|all-to-all)"
+                               r"(?:-start|-done)?\(")
 
 
 @dataclasses.dataclass
@@ -235,6 +240,11 @@ def loop_seconds(trace, dev):
                               if LOOP.match(e.name)), lo, hi))
 
 
+def is_collective(e):
+    """Whether the operation moves data between chips."""
+    return bool(COLLECTIVE.search(e.name) or COLLECTIVE_OPCODE.search(e.text))
+
+
 def collective_seconds(trace, dev):
     """(all, exposed) seconds of collectives on one device: their
     operations and asynchronous spans, and the part of those during which
@@ -242,10 +252,9 @@ def collective_seconds(trace, dev):
     lo, hi = window(trace)
     coll, other = [], []
     for e in trace.devices.get(dev, []):
-        (coll if COLLECTIVE.search(e.name) else other).append(
-            (e.start, e.end))
+        (coll if is_collective(e) else other).append((e.start, e.end))
     for e in trace.asyncs.get(dev, []):
-        if COLLECTIVE.search(e.name):
+        if is_collective(e):
             coll.append((e.start, e.end))
     coll = clip(union(coll), lo, hi)
     return measure(coll), measure(subtract(coll, other))
