@@ -12,6 +12,10 @@ plain ``{span name: ms}`` dict; ``CoLearner.run_round`` hands it to the
 round's ``RoundLog.host_ms``. The spans a round opens do not overlap
 each other, so their times sum to no more than the round's.
 
+``count(name, value)`` adds a program counter to the open round's record
+(``RoundRecord.counters``): numbers the round's device work summed and
+the round's one fetch brought back.
+
 Finished rounds are also kept, newest last, in a bounded process-wide
 history (``recent()``): a reader that never held the learner -- a
 benchmark that reads the rounds of its timed window after the learner is
@@ -35,16 +39,20 @@ HISTORY = 4096
 
 _open: contextvars.ContextVar = contextvars.ContextVar("round_record",
                                                        default=None)
+_open_counters: contextvars.ContextVar = contextvars.ContextVar(
+    "round_counters", default=None)
 _history: collections.deque = collections.deque(maxlen=HISTORY)
 
 
 @dataclasses.dataclass(frozen=True)
 class RoundRecord:
     """One finished round: its ``perf_counter`` start and end, seconds,
-    and the milliseconds of each span it opened (its own included)."""
+    the milliseconds of each span it opened (its own included), and its
+    counters."""
     start: float
     end: float
     host_ms: dict
+    counters: dict = dataclasses.field(default_factory=dict)
 
 
 @contextlib.contextmanager
@@ -62,12 +70,21 @@ def span(name):
                 record[name] = record.get(name, 0.0) + ms
 
 
+def count(name, value):
+    """Add ``value`` to the open round's counter ``name``, if a round is
+    open."""
+    counters = _open_counters.get()
+    if counters is not None:
+        counters[name] = counters.get(name, 0) + value
+
+
 @contextlib.contextmanager
 def round_span(name):
     """The span of a whole round: yields the round's fresh record, which
     holds every span opened inside it once the round has closed."""
-    record = {}
+    record, counters = {}, {}
     token = _open.set(record)
+    ctoken = _open_counters.set(counters)
     try:
         with jax.profiler.TraceAnnotation(name):
             t0 = time.perf_counter()
@@ -75,8 +92,9 @@ def round_span(name):
             t1 = time.perf_counter()
     finally:
         _open.reset(token)
+        _open_counters.reset(ctoken)
     record[name] = 1e3 * (t1 - t0)
-    _history.append(RoundRecord(t0, t1, record))
+    _history.append(RoundRecord(t0, t1, record, counters))
 
 
 def recent():

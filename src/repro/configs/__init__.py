@@ -18,6 +18,7 @@ _MODULES = {
     "internlm2-1.8b": "internlm2_1_8b",
     "deepseek-v3-671b": "deepseek_v3_671b",
     "jamba-v0.1-52b": "jamba_v0_1_52b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
 }
 
 ARCH_IDS = tuple(_MODULES)

@@ -49,6 +49,17 @@ class ModelConfig:
     n_shared_experts: int = 0
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
+    # The router: "softmax" (top-k of the probabilities, Switch-style aux
+    # loss) or "sigmoid" (DeepSeek-V3's noaux_tc: top-k of the scores plus
+    # a correction bias, the sequence-wise balance loss). The chosen
+    # weights are normalised over the k, then scaled by ``routed_scale``.
+    router_score: str = "softmax"
+    routed_scale: float = 1.0
+    # Experts held here: 0 holds every expert behind a capacity buffer
+    # (tokens over capacity are dropped); H > 0 holds experts [0, H) of
+    # the n_experts the router scores, a chip's share under expert
+    # parallelism, and drops nothing.
+    experts_held: int = 0
 
     # SSM (Mamba)
     ssm_state_dim: int = 16

@@ -1527,6 +1527,15 @@ class FusedEngine(RoundEngine):
         return _FusedRunner(learner, self.chunk)
 
 
+def _sum_counters(parts):
+    """Host sums, by name, of the counters of a round's fetched parts."""
+    out = {}
+    for part in parts:
+        for name, value in part.items():
+            out[name] = out.get(name, 0) + int(np.sum(value))
+    return out
+
+
 def _live_loss_means(losses, live_np):
     """Per-epoch mean loss over the LIVE participants (all K when
     ``live_np`` is None — the static path, bit-compatible)."""
@@ -1765,7 +1774,8 @@ class _FusedRunner:
                 new_avg = aux["new_avg"]
                 # the round's single host sync (scalars/loss curves only —
                 # the aggregated model itself stays on device)
-                wanted = (aux["losses"], aux["lrs"], aux["rel"])
+                wanted = (aux["losses"], aux["lrs"], aux["rel"],
+                          aux["counters"])
                 if gated:
                     wanted += (aux["div"], aux["synced"])
             elif not gated:
@@ -1778,20 +1788,21 @@ class _FusedRunner:
             # in T_i (which ILE doubles); chain chunk executables instead.
             # j0/T_i/ge0/sched/total are traced, so chunks reuse one
             # compiled program across doublings AND schedule swaps.
-            lparts, rparts, j0 = [], [], 0
+            lparts, rparts, cparts, j0 = [], [], [], 0
             while j0 < T_i:
                 C = min(self.chunk, T_i - j0)
                 with spans.span("colearn.input"):
                     batches = engine_mod.stack_epoch_batches(
                         [epoch_batches_fn(i, j) for j in range(j0, j0 + C)])
                 with spans.span("colearn.dispatch"):
-                    params, opt_st, l, r = self._epochs(
+                    params, opt_st, l, r, c = self._epochs(
                         state["params"], state["opt"], batches, *mask_args,
                         engine_mod.stage(j0, np.int32),
                         engine_mod.stage(T_i, np.int32), ge0, sched, total)
                     state["params"], state["opt"] = params, opt_st
                 lparts.append(l)
                 rparts.append(r)
+                cparts.append(c)
                 j0 += C
             with spans.span("colearn.dispatch"):
                 # stateful codec: the residual enters finalize right after
@@ -1809,7 +1820,7 @@ class _FusedRunner:
                         state["residual"] = out_res
                     else:
                         out_p, out_o, rel_t, div_t, sync_t, new_avg = out
-                    wanted = (lparts, rparts, rel_t, div_t, sync_t)
+                    wanted = (lparts, rparts, rel_t, cparts, div_t, sync_t)
                 else:
                     if self._live:
                         # live variant threads opt_state so dead rows keep
@@ -1825,15 +1836,18 @@ class _FusedRunner:
                         state["residual"] = out_res
                     else:
                         out_p, out_o, rel_t, new_avg = out
-                    wanted = (lparts, rparts, rel_t)
+                    wanted = (lparts, rparts, rel_t, cparts)
                 state["params"], state["opt"] = out_p, out_o
         with spans.span("colearn.fetch"):
             got = jax.device_get(wanted)
         if gated:
-            losses, lrs, rel_dev, div_dev, sync_dev = got
+            losses, lrs, rel_dev, counts, div_dev, sync_dev = got
         else:
-            (losses, lrs, rel_dev), div_dev, sync_dev = got, None, True
-        if not single:
+            (losses, lrs, rel_dev, counts), div_dev, sync_dev = (got, None,
+                                                                 True)
+        if single:
+            counts = [counts]
+        else:
             losses = np.concatenate(losses)
             lrs = np.concatenate(lrs)
         synced = bool(sync_dev)
@@ -1846,7 +1860,8 @@ class _FusedRunner:
         return learner._finish_round(state, i, T_i, rel,
                                      _live_loss_means(losses, live_np),
                                      float(lrs[0]), float(lrs[-1]),
-                                     out_p, out_o, new_avg, synced=synced)
+                                     out_p, out_o, new_avg, synced=synced,
+                                     counters=_sum_counters(counts))
 
 
 # ---------------------------------------------------------------------------

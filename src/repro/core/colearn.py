@@ -73,6 +73,10 @@ class RoundLog:
                              # -1 only on legacy hand-built logs)
     host_ms: dict | None = None  # host span name -> ms (``analysis.spans``);
                                  # None on hand-built logs
+    counters: dict | None = None  # the loss's counters summed over the
+                                  # round by the fused engine
+                                  # (``spans.count``); None on hand-built
+                                  # logs
 
 
 @dataclass
@@ -401,7 +405,7 @@ class CoLearner:
 
     def _finish_round(self, state, i, T_i, rel, local_losses, lr_first,
                       lr_last, averaged, fresh_opt, new_avg, synced=True,
-                      residual=None):
+                      residual=None, counters=None):
         """The one round state transition, shared verbatim by both engines.
 
         ``fresh_opt`` is the per-participant opt reset (opt state is
@@ -413,6 +417,8 @@ class CoLearner:
         ``rel`` — and the round bills zero wire bytes. ``residual`` is the
         error-feedback codec's post-round memory (None for stateless
         codecs or when the runner already stored it on ``state``).
+        ``counters`` are the loss's counters summed over the round, on the
+        host; they go to the log entry and to the open round's record.
         """
         with spans.span("colearn.finish"):
             state["params"], state["opt"] = averaged, fresh_opt
@@ -449,9 +455,12 @@ class CoLearner:
                 comm = self.aggregator.comm_bytes(self.codec,
                                                   state["params"], i)
             state["round"] = i + 1
+            counters = counters or {}
+            for name, value in counters.items():
+                spans.count(name, value)
             state["log"].append(RoundLog(i, T_i, lr_first, lr_last, rel,
                                          local_losses, comm, synced,
-                                         live=n_live))
+                                         live=n_live, counters=counters))
         return state
 
     # legacy handles used by tests/benchmarks to poke at the fused

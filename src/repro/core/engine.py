@@ -115,6 +115,12 @@ from repro.optim.optimizers import apply_updates
 #: gated divergence carry ``schedule.EQ4_REL``
 LOCAL_STEP = "local_step"
 EQ2_WIRE = "eq2_wire"
+#: named scopes inside the local step, opened in the layer body: the
+#: latent-attention mixer, the whole expert layer (router, dispatch,
+#: grouped products, combine, shared experts) and its grouped products
+MLA = "mla"
+MOE = "moe"
+MOE_GMM = "moe_gmm"
 
 
 def stage(value, dtype=None):
@@ -167,11 +173,14 @@ def unstack_first_live(stacked, live_row):
 
 
 def make_epoch_fn(loss_fn, opt, spmd_axis_name=None, masked=False,
-                  live=False):
+                  live=False, counters=False):
     """One local epoch for all K participants (vmapped).
 
     Returns epoch_fn(stacked_params, opt_state, batches, lr) ->
-    (stacked_params, opt_state, per-participant mean loss). This is THE
+    (stacked_params, opt_state, per-participant mean loss[, counters]);
+    with ``counters=True`` the fourth output holds the counters the loss's
+    metrics carry (``{}`` where they carry none), each summed per
+    participant over the epoch's committed steps. This is THE
     local-epoch body: the python reference loop jits it directly and the
     fused engine scans over it, so the SGD semantics cannot diverge.
 
@@ -201,8 +210,9 @@ def make_epoch_fn(loss_fn, opt, spmd_axis_name=None, masked=False,
                 batch, valid = xs
             else:
                 batch = xs
-            (loss, _), grads = jax.value_and_grad(
+            (loss, metrics), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params, batch)
+            counts = _counters(metrics)
             upd, new_ostate = opt.update(grads, ostate, params, lr)
             new_params = apply_updates(params, upd)
             # identity carry on padding slots / dead participants: nothing
@@ -218,9 +228,12 @@ def make_epoch_fn(loss_fn, opt, spmd_axis_name=None, masked=False,
                 new_params = jax.tree.map(keep, new_params, params)
                 new_ostate = jax.tree.map(keep, new_ostate, ostate)
                 loss = jnp.where(gate, loss, 0.0)
-            return (new_params, new_ostate), loss
+                counts = jax.tree.map(lambda c: jnp.where(gate, c, 0), counts)
+            return (new_params, new_ostate), (loss, counts)
         xs = (pbatches, pmask) if masked else pbatches
-        (params, ostate), losses = jax.lax.scan(step, (params, ostate), xs)
+        (params, ostate), (losses, counts) = jax.lax.scan(
+            step, (params, ostate), xs)
+        counts = jax.tree.map(lambda c: c.sum(0), counts)
         if masked or live:
             denom = pmask.sum() if masked else losses.size
             if live:
@@ -228,6 +241,8 @@ def make_epoch_fn(loss_fn, opt, spmd_axis_name=None, masked=False,
             mean = losses.sum() / jnp.maximum(denom, 1)
         else:
             mean = losses.mean()
+        if counters:
+            return params, ostate, mean, counts
         return params, ostate, mean
 
     # explicit signature per variant so vmap's positional in_axes line up
@@ -250,6 +265,19 @@ def make_epoch_fn(loss_fn, opt, spmd_axis_name=None, masked=False,
     in_axes = ((0, 0, 0, None) + ((0,) if masked else ())
                + ((0,) if live else ()))
     return jax.vmap(one_participant, in_axes=in_axes, **vmap_kw)
+
+
+def _counters(metrics):
+    """The counters a loss's metrics carry under ``"counters"``."""
+    if isinstance(metrics, dict):
+        return metrics.get("counters", {})
+    return {}
+
+
+def _round_counters(counts):
+    """A round's counters: the (T, K) per-epoch, per-participant sums of
+    the epoch scan, summed."""
+    return jax.tree.map(jnp.sum, counts)
 
 
 def _make_epoch_scan(epoch_fn, lr_fn, masked=False, live=False):
@@ -277,9 +305,9 @@ def _make_epoch_scan(epoch_fn, lr_fn, masked=False, live=False):
             params, ostate = carry
             j, ebatches = xs
             lr = lr_fn(sched, j, T_i, global_epoch0 + j, total)
-            params, ostate, loss = epoch_fn(params, ostate, ebatches, lr,
-                                            *extra)
-            return (params, ostate), (loss, lr)
+            params, ostate, loss, counts = epoch_fn(params, ostate,
+                                                    ebatches, lr, *extra)
+            return (params, ostate), (loss, lr, counts)
 
         # the scope wraps the scan, so the loop op itself carries it
         with jax.named_scope(LOCAL_STEP):
@@ -762,7 +790,8 @@ def make_fused_round(loss_fn, opt, *, lr_fn=None, compress_fn=None,
     Returns round_fn(stacked_params, opt_state, batches, global_epoch0,
     sched, total, agg_weights=None) -> (aggregated_params, fresh_opt_state,
     aux) with aux = {losses (T,K), lrs (T,), rel (scalar), new_avg
-    (unstacked slot-0 model)}. ``batches`` is a (T_i, K, n_batches, ...)
+    (unstacked slot-0 model), counters (the loss's counters summed over
+    the round's steps and participants)}. ``batches`` is a (T_i, K, n_batches, ...)
     pytree; ``global_epoch0``/``sched``/``total`` are traced (an int32
     offset, the schedule parameter pack, the int32 epoch budget) so
     neither an ELR step, a per-round η^i, a budget update, nor a built-in
@@ -800,7 +829,8 @@ def make_fused_round(loss_fn, opt, *, lr_fn=None, compress_fn=None,
         lr_fn = switch_lr
     scan_epochs = _make_epoch_scan(
         make_epoch_fn(loss_fn, opt, spmd_axis_name, masked=masked,
-                      live=live), lr_fn, masked=masked, live=live)
+                      live=live, counters=True), lr_fn, masked=masked,
+        live=live)
     agg = as_aggregate_fn(aggregate_fn, compress_fn, average_fn)
 
     if gated:
@@ -811,7 +841,7 @@ def make_fused_round(loss_fn, opt, *, lr_fn=None, compress_fn=None,
                        live_row, global_epoch0, sched, total, sync_ref,
                        delta, agg_weights=None):
             T_i = jax.tree.leaves(batches)[0].shape[0]
-            (params, opt_out), (losses, lrs) = scan_epochs(
+            (params, opt_out), (losses, lrs, counts) = scan_epochs(
                 stacked_params, opt_state, batches, 0, T_i, global_epoch0,
                 sched, total, mask, live_row)
             res_in = (residual,) if stateful else ()
@@ -826,7 +856,8 @@ def make_fused_round(loss_fn, opt, *, lr_fn=None, compress_fn=None,
             else:
                 out_p, out_o, rel, div, do_sync, new_ref = out
             aux = {"losses": losses, "lrs": lrs, "rel": rel, "div": div,
-                   "synced": do_sync, "new_avg": new_ref}
+                   "synced": do_sync, "new_avg": new_ref,
+                   "counters": _round_counters(counts)}
             if stateful:
                 aux["residual"] = out_res
             return out_p, out_o, aux
@@ -845,7 +876,7 @@ def make_fused_round(loss_fn, opt, *, lr_fn=None, compress_fn=None,
             else:
                 # round entry: every slot holds the shared model w̄^{i-1}
                 old_avg = averaging.unstack_participant(stacked_params, 0)
-            (params, opt_out), (losses, lrs) = scan_epochs(
+            (params, opt_out), (losses, lrs, counts) = scan_epochs(
                 stacked_params, opt_state, batches, 0, T_i, global_epoch0,
                 sched, total, mask, live_row)
             res_in = (residual,) if stateful else ()
@@ -861,7 +892,7 @@ def make_fused_round(loss_fn, opt, *, lr_fn=None, compress_fn=None,
             else:
                 averaged, fresh_opt, rel, new_avg = out
             aux = {"losses": losses, "lrs": lrs, "rel": rel,
-                   "new_avg": new_avg}
+                   "new_avg": new_avg, "counters": _round_counters(counts)}
             if stateful:
                 aux["residual"] = new_res
             return averaged, fresh_opt, aux
@@ -876,7 +907,8 @@ def make_fused_epochs(loss_fn, opt, *, lr_fn=None, spmd_axis_name=None,
     """Memory-bounded building block: a scan over ONE CHUNK of epochs.
 
     Returns epochs_fn(stacked_params, opt_state, batches, j0, T_i, ge0,
-    sched, total) -> (stacked_params, opt_state, losses (C,K), lrs (C,)).
+    sched, total) -> (stacked_params, opt_state, losses (C,K), lrs (C,),
+    counters (summed over the chunk)).
     j0/T_i/ge0/sched/total are traced, so the executable is shared across
     chunks, across T_i doublings, across budget updates, and across
     built-in schedule swaps; only a distinct chunk length C recompiles.
@@ -890,16 +922,17 @@ def make_fused_epochs(loss_fn, opt, *, lr_fn=None, spmd_axis_name=None,
         lr_fn = switch_lr
     scan_epochs = _make_epoch_scan(
         make_epoch_fn(loss_fn, opt, spmd_axis_name, masked=masked,
-                      live=live), lr_fn, masked=masked, live=live)
+                      live=live, counters=True), lr_fn, masked=masked,
+        live=live)
 
     def epochs_body(stacked_params, opt_state, _residual, batches, mask,
                     live_row, j0, T_i, global_epoch0, sched, total):
         # epochs never touch the codec residual (it only moves at the
         # finalize); _bind_mask_live binds it to None here
-        (params, ostate), (losses, lrs) = scan_epochs(
+        (params, ostate), (losses, lrs, counts) = scan_epochs(
             stacked_params, opt_state, batches, j0, T_i, global_epoch0,
             sched, total, mask, live_row)
-        return params, ostate, losses, lrs
+        return params, ostate, losses, lrs, _round_counters(counts)
 
     epochs_fn = _bind_mask_live(epochs_body, masked, live)
     donate_argnums = (0, 1) if donate else ()

@@ -68,12 +68,16 @@ def mask_bias(q_pos, k_pos, window):
 
 
 def chunked_attention(q, k, v, *, n_kv_heads, window=0, q_offset=0,
-                      chunk_q=1024, chunk_kv=1024, softmax_scale=None):
+                      chunk_q=1024, chunk_kv=1024, softmax_scale=None,
+                      recompute=False):
     """Online-softmax attention. q:(B,Sq,H,hd) k,v:(B,Sk,KV,hd/hd_v).
 
     Chunked over both Sq (outer scan) and Sk (inner scan) so the peak score
     tensor is (B,H,cq,ck) regardless of sequence length. KV==1 uses the
-    shared-KV (MQA/MLA) path.
+    shared-KV (MQA/MLA) path. Differentiated as it stands, the scans save every chunk pair's scores
+    for the backward pass: the whole (B,H,Sq,Sk) matrix. ``recompute``
+    saves only the scans' carries and recomputes each chunk pair's scores
+    in the backward pass.
     """
     B, Sq, H, hd = q.shape
     Sk = k.shape[1]
@@ -130,12 +134,14 @@ def chunked_attention(q, k, v, *, n_kv_heads, window=0, q_offset=0,
         m0 = jnp.full((B, H, cq), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, H, cq), jnp.float32)
         a0 = jnp.zeros((B, H, cq, hd_v), jnp.float32)
-        (m, l, acc), _ = jax.lax.scan(kv_step, (m0, l0, a0),
-                                      ((kg, vg), jnp.arange(nk)))
+        (m, l, acc), _ = jax.lax.scan(
+            jax.checkpoint(kv_step) if recompute else kv_step, (m0, l0, a0),
+            ((kg, vg), jnp.arange(nk)))
         out = acc / jnp.maximum(l, 1e-30)[..., None]       # (B,H,cq,hd_v)
         return None, out.transpose(0, 2, 1, 3)             # (B,cq,H,hd_v)
 
-    _, outs = jax.lax.scan(q_step, None, (qg, jnp.arange(nq)))
+    _, outs = jax.lax.scan(jax.checkpoint(q_step) if recompute else q_step,
+                           None, (qg, jnp.arange(nq)))
     out = outs.transpose(1, 0, 2, 3, 4).reshape(B, Sq, H, hd_v)
     return out.astype(q.dtype)
 

@@ -63,18 +63,18 @@ def layer_apply(p, kind, x, cfg, positions, impl="ref"):
     else:
         y = xl.slstm_apply(p["mixer"], h, cfg, impl)
     x = x + y
-    aux = jnp.zeros((), jnp.float32)
+    aux, counters = jnp.zeros((), jnp.float32), {}
     if ffn != "-":
         h = rmsnorm_apply(p["norm2"], x, cfg.norm_eps)
         if ffn == "dense":
             y = ffn_apply(p["ffn"], h)
         elif ffn == "moe":
-            y, aux = moe_mod.moe_apply(p["ffn"], h, cfg)
+            y, aux, counters = moe_mod.moe_apply(p["ffn"], h, cfg)
         else:
-            ym, aux = moe_mod.moe_apply(p["ffn"]["moe"], h, cfg)
+            ym, aux, counters = moe_mod.moe_apply(p["ffn"]["moe"], h, cfg)
             y = ym + ffn_apply(p["ffn"]["dense"], h)
         x = x + y
-    return constrain(x, ("dp", "r", "r")), aux
+    return constrain(x, ("dp", "r", "r")), aux, counters
 
 
 def layer_cache_init(kind, cfg, batch, seq_len, dtype):
@@ -103,9 +103,9 @@ def layer_decode(p, kind, x, cfg, cache, pos):
         if ffn == "dense":
             y = ffn_apply(p["ffn"], h)
         elif ffn == "moe":
-            y, _ = moe_mod.moe_apply(p["ffn"], h, cfg)
+            y, _, _ = moe_mod.moe_apply(p["ffn"], h, cfg)
         else:
-            ym, _ = moe_mod.moe_apply(p["ffn"]["moe"], h, cfg)
+            ym, _, _ = moe_mod.moe_apply(p["ffn"]["moe"], h, cfg)
             y = ym + ffn_apply(p["ffn"]["dense"], h)
         x = x + y
     return x, new_cache
@@ -153,21 +153,31 @@ def embed_inputs(params, cfg, batch):
     return constrain(x, ("dp", "r", "r"))
 
 
+def _add_counters(a, b):
+    # in name order: the traced program, and so its compile-cache key,
+    # must not follow the process's string hashing
+    return {n: a.get(n, 0) + b.get(n, 0) for n in sorted({*a, *b})}
+
+
 def forward(params, cfg, batch, lowering="scan", impl="ref", remat=True,
-            return_hidden=False, apply_head=True):
-    """Returns (logits, aux_loss[, hidden])."""
+            return_hidden=False, apply_head=True, return_counters=False):
+    """Returns (logits, aux_loss[, hidden][, counters]); ``counters`` sums
+    each layer's counters (``moe.HELD_PICKS``, ``moe.PICKS``) over the
+    layers."""
     x = embed_inputs(params, cfg, batch)
     B, S = x.shape[0], x.shape[1]
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
     aux_total = jnp.zeros((), jnp.float32)
+    counters = {}
 
     for seg_params, (pattern, repeats) in zip(params["segments"], cfg.segments):
         def body(x, p_r, _pattern=pattern):
-            aux = jnp.zeros((), jnp.float32)
+            aux, counts = jnp.zeros((), jnp.float32), {}
             for j, kind in enumerate(_pattern):
-                x, a = layer_apply(p_r[f"p{j}"], kind, x, cfg, positions, impl)
-                aux = aux + a
-            return x, aux
+                x, a, c = layer_apply(p_r[f"p{j}"], kind, x, cfg, positions,
+                                      impl)
+                aux, counts = aux + a, _add_counters(counts, c)
+            return x, (aux, counts)
         if remat:
             body = jax.checkpoint(body)
         # 'unroll' uses scan(unroll=R), NOT a python loop over t[r] slices:
@@ -175,8 +185,9 @@ def forward(params, cfg, batch, lowering="scan", impl="ref", remat=True,
         # replicates the weights (measured: 13x per-layer FLOPs at qwen2
         # prefill — EXPERIMENTS.md §Perf cycle 2b)
         unroll = repeats if lowering == "unroll" else 1
-        x, auxs = jax.lax.scan(body, x, seg_params, unroll=unroll)
+        x, (auxs, counts) = jax.lax.scan(body, x, seg_params, unroll=unroll)
         aux_total = aux_total + auxs.sum()
+        counters = _add_counters(counters, jax.tree.map(jnp.sum, counts))
 
     h = rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
     logits = None
@@ -184,18 +195,25 @@ def forward(params, cfg, batch, lowering="scan", impl="ref", remat=True,
         logits = lm_head_apply(params["embed"], params.get("head"), h,
                                cfg.tie_embeddings)
         logits = constrain(logits, ("dp", "r", "model"))  # vocab stays sharded
+    out = (logits, aux_total)
     if return_hidden:
-        return logits, aux_total, h
-    return logits, aux_total
+        out += (h,)
+    if return_counters:
+        out += (counters,)
+    return out
 
 
 def loss_fn(params, cfg, batch, lowering="scan", impl="ref", remat=True):
-    """Next-token LM loss (+aux, +MTP when configured). labels: -1 = ignore."""
+    """Next-token LM loss (+aux, +MTP when configured). labels: -1 = ignore.
+
+    The metrics hold the layers' summed ``counters`` (empty where no layer
+    counts)."""
     need_h = bool(cfg.mtp_depth)
-    out = forward(params, cfg, batch, lowering, impl, remat, return_hidden=need_h)
+    out = forward(params, cfg, batch, lowering, impl, remat,
+                  return_hidden=need_h, return_counters=True)
     logits, aux = out[0], out[1]
     loss = softmax_xent(logits, batch["labels"])
-    metrics = {"lm_loss": loss, "aux_loss": aux}
+    metrics = {"lm_loss": loss, "aux_loss": aux, "counters": out[-1]}
     if need_h:
         h = out[2]
         mtp = params["mtp"]
@@ -208,8 +226,8 @@ def loss_fn(params, cfg, batch, lowering="scan", impl="ref", remat=True):
         positions = jnp.broadcast_to(
             jnp.arange(S - 1, dtype=jnp.int32), (h.shape[0], S - 1))
         last_kind = cfg.segments[-1][0][-1]
-        x2, _ = layer_apply(jax.tree.map(lambda t: t[0], mtp["layer"]),
-                            last_kind, x2, cfg, positions, impl)
+        x2, _, _ = layer_apply(jax.tree.map(lambda t: t[0], mtp["layer"]),
+                               last_kind, x2, cfg, positions, impl)
         h2 = rmsnorm_apply(params["final_norm"], x2, cfg.norm_eps)
         logits2 = lm_head_apply(params["embed"], params.get("head"), h2,
                                 cfg.tie_embeddings)

@@ -67,7 +67,8 @@ def test_smoke_decode_step(arch):
 
 
 @pytest.mark.parametrize("arch", ["internlm2-1.8b", "xlstm-1.3b",
-                                  "jamba-v0.1-52b", "deepseek-v3-671b"])
+                                  "jamba-v0.1-52b", "deepseek-v3-671b",
+                                  "moonlight-16b-a3b"])
 def test_decode_matches_forward(arch):
     """Token-by-token decode logits == full-sequence forward logits."""
     cfg = get_smoke_config(arch)
